@@ -291,6 +291,13 @@ def report_payload_observation(store: PlanStore, plan: ConcurrencyPlan | None,
         predicted=predicted, observed=dt, kind=OBS_FINISH))
 
 
+def _host_is_backend() -> bool:
+    """Will jax run on the host CPU?  Read from ``JAX_PLATFORMS`` without
+    starting jax, because the host device count is fixed when it starts:
+    only a platform list led by ``cpu`` says so."""
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+
+
 def _request_host_devices(n: int) -> None:
     """Ask XLA for ``n`` host-platform devices.  Appends the flag to
     ``XLA_FLAGS`` unless a device count is already pinned there (the
@@ -331,20 +338,20 @@ class RealGraphExecutor:
     strictly earlier submissions.
 
     ``n_devices`` maps the cluster daemon's simulated machines onto
-    DISTINCT host JAX devices: it requests that many host-platform XLA
-    devices (``--xla_force_host_platform_device_count``, which only
-    takes effect if set before jax's first initialization — jax locks
-    the device count then) and ``device_for(machine)`` returns the
-    device a machine's payloads should land on.  Payload execution and
-    the device mapping degrade gracefully without jax: ``device_for``
-    returns None and payloads run unpinned."""
+    DISTINCT devices of jax's default backend: ``device_for(machine)``
+    returns the device a machine's payloads should land on (a chip on an
+    accelerator host).  When that backend is the host CPU, it requests
+    that many host-platform XLA devices
+    (``--xla_force_host_platform_device_count``, which only takes effect
+    if set before jax's first initialization — jax locks the device
+    count then)."""
 
     def __init__(self, max_workers: int = 2, *, persistent: bool = False,
                  n_devices: int | None = None):
         self.max_workers = max_workers
         self.n_devices = n_devices
         self._devices: tuple | None = None     # resolved lazily
-        if n_devices is not None and n_devices > 1:
+        if n_devices is not None and n_devices > 1 and _host_is_backend():
             _request_host_devices(n_devices)
         self._pool: ThreadPoolExecutor | None = (
             ThreadPoolExecutor(max_workers=max_workers)
@@ -352,19 +359,14 @@ class RealGraphExecutor:
 
     # ---- persistent (service-daemon) mode ------------------------------
     def device_for(self, machine: int | None):
-        """The host JAX device simulated machine ``machine`` maps to
+        """The JAX device simulated machine ``machine`` maps to
         (round-robin when jax granted fewer devices than machines; None
-        when unmapped, jax-less, or ``n_devices`` was never set)."""
+        when unmapped or ``n_devices`` was never set)."""
         if machine is None or self.n_devices is None:
             return None
         if self._devices is None:
-            try:
-                import jax
-                self._devices = tuple(jax.devices("cpu"))
-            except Exception:  # noqa: BLE001 - jax-less: run unpinned
-                self._devices = ()
-        if not self._devices:
-            return None
+            import jax
+            self._devices = tuple(jax.devices())
         return self._devices[machine % len(self._devices)]
 
     def submit_op(self, op, deps: dict[int, object],

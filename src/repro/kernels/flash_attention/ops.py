@@ -1,9 +1,8 @@
 """Jit'd public wrapper for the flash attention kernel.
 
-On TPU the Pallas kernel runs compiled; elsewhere (this CPU container)
-``interpret=True`` executes the kernel body in Python for validation, and
-``flash_attention(..., fallback=True)`` routes to the jnp oracle — which
-is also what the models' forward passes use on CPU.
+On TPU the Pallas kernel runs compiled; on the CPU ``interpret=True``
+executes the kernel body in Python for validation against the jnp oracle
+in ``ref.py``.
 """
 
 from __future__ import annotations
@@ -13,31 +12,15 @@ import functools
 import jax
 
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
-from repro.kernels.flash_attention.ref import attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "block_q", "block_k", "interpret", "fallback"))
+    "causal", "window", "block_q", "block_k", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = False,
-                    fallback: bool = False) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: (B,S,H,D); k/v: (B,S,K,D) -> (B,S,H,D)."""
-    if fallback:
-        return attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention_kernel(
         q, k, v, causal=causal, window=window,
         block_q=block_q, block_k=block_k, interpret=interpret)
-
-
-def flash_attention_auto(q, k, v, *, causal=True, window=None,
-                         block_q=128, block_k=128):
-    """Kernel on TPU, oracle elsewhere — the model-facing entry point."""
-    return flash_attention(q, k, v, causal=causal, window=window,
-                           block_q=block_q, block_k=block_k,
-                           fallback=not _on_tpu())

@@ -31,18 +31,11 @@ def _rglru_kernel(a_ref, x_ref, o_ref, hlast_ref, h_ref, *, chunk: int):
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[0].astype(jnp.float32)             # (C,R)
-    x = x_ref[0].astype(jnp.float32)
-    b = jnp.sqrt(jnp.maximum(1.0 - a * a, 1e-12)) * x
-
     def step(t, h):
-        a_t = jax.lax.dynamic_slice_in_dim(a, t, 1, axis=0)   # (1,R)
-        b_t = jax.lax.dynamic_slice_in_dim(b, t, 1, axis=0)
-        h = a_t * h + b_t
-        # all-slice index: an int dim-0 index breaks older pallas
-        # NDIndexer handling (idx.indices entries must have .shape)
-        pl.store(o_ref, (pl.ds(0, 1), pl.ds(t, 1), slice(None)),
-                 h[None].astype(o_ref.dtype))
+        a_t = a_ref[0, pl.ds(t, 1), :].astype(jnp.float32)   # (1,R)
+        x_t = x_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        h = a_t * h + jnp.sqrt(jnp.maximum(1.0 - a_t * a_t, 1e-12)) * x_t
+        o_ref[0, pl.ds(t, 1), :] = h.astype(o_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, chunk, step, h_ref[...])
@@ -50,7 +43,7 @@ def _rglru_kernel(a_ref, x_ref, o_ref, hlast_ref, h_ref, *, chunk: int):
 
     @pl.when(ci == nc - 1)
     def _flush():
-        hlast_ref[...] = h.astype(hlast_ref.dtype)
+        hlast_ref[0] = h.astype(hlast_ref.dtype)
 
 
 def rglru_kernel(a: jax.Array, x: jax.Array, *, chunk: int = 128,
@@ -71,13 +64,13 @@ def rglru_kernel(a: jax.Array, x: jax.Array, *, chunk: int = 128,
         ],
         out_specs=(
             pl.BlockSpec((1, chunk, r), lambda b_, c_: (b_, c_, 0)),
-            pl.BlockSpec((1, r), lambda b_, c_: (b_, 0)),
+            pl.BlockSpec((1, 1, r), lambda b_, c_: (b_, 0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bsz, s, r), x.dtype),
-            jax.ShapeDtypeStruct((bsz, r), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, 1, r), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((1, r), jnp.float32)],
         interpret=interpret,
     )(a, x)
-    return h, h_last
+    return h, h_last.reshape(bsz, r)

@@ -1,4 +1,4 @@
-"""Jit'd wrapper for the RG-LRU kernel with CPU fallback to the oracle."""
+"""Jit'd public wrapper for the RG-LRU kernel (``interpret=True`` on CPU)."""
 
 from __future__ import annotations
 
@@ -7,18 +7,9 @@ import functools
 import jax
 
 from repro.kernels.rglru.kernel import rglru_kernel
-from repro.kernels.rglru.ref import rglru_ref
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "fallback"))
-def rglru(a, x, *, chunk: int = 128, interpret: bool = False,
-          fallback: bool = False):
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def rglru(a, x, *, chunk: int = 128, interpret: bool = False):
     """a, x: (B,S,R) -> (h (B,S,R), h_last (B,R))."""
-    if fallback:
-        return rglru_ref(a, x)
     return rglru_kernel(a, x, chunk=chunk, interpret=interpret)
-
-
-def rglru_auto(a, x, *, chunk: int = 128):
-    on_tpu = jax.default_backend() == "tpu"
-    return rglru(a, x, chunk=chunk, fallback=not on_tpu)

@@ -1,4 +1,4 @@
-"""Jit'd wrapper for the WKV6 kernel with CPU fallback to the oracle."""
+"""Jit'd public wrapper for the WKV6 kernel (``interpret=True`` on CPU)."""
 
 from __future__ import annotations
 
@@ -7,18 +7,9 @@ import functools
 import jax
 
 from repro.kernels.rwkv6.kernel import wkv6_kernel
-from repro.kernels.rwkv6.ref import wkv6_ref
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "fallback"))
-def wkv6(r, k, v, w, u, *, chunk: int = 64, interpret: bool = False,
-         fallback: bool = False):
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def wkv6(r, k, v, w, u, *, chunk: int = 64, interpret: bool = False):
     """r,k,v,w: (B,H,S,D); u: (H,D) -> (out (B,H,S,D), state (B,H,D,D))."""
-    if fallback:
-        return wkv6_ref(r, k, v, w, u)
     return wkv6_kernel(r, k, v, w, u, chunk=chunk, interpret=interpret)
-
-
-def wkv6_auto(r, k, v, w, u, *, chunk: int = 64):
-    on_tpu = jax.default_backend() == "tpu"
-    return wkv6(r, k, v, w, u, chunk=chunk, fallback=not on_tpu)

@@ -17,7 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.configs import ARCH_IDS, SHAPES, ShapeSpec, get_config, \
     skip_reason  # noqa: E402
 from repro.hw import V5E, parse_collectives, dominant_term  # noqa: E402
-from repro.launch.mesh import make_production_mesh, pod_size, use_mesh  # noqa: E402
+from repro.launch.mesh import make_production_mesh, pod_size  # noqa: E402
 from repro.models import zoo  # noqa: E402
 from repro.models.common import ModelConfig, ShardingPlan, default_plan  # noqa: E402
 from repro.optim import AdamWConfig  # noqa: E402
@@ -138,12 +138,7 @@ def _batch_shardings(cfg, shape, plan, mesh, specs):
 
 
 def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() normalized: older jax returns a
-    per-device list, newer a single dict."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    return compiled.cost_analysis() or {}
 
 
 def lower_cell(cfg: ModelConfig, shape: ShapeSpec, mesh, *,
@@ -170,7 +165,7 @@ def lower_cell(cfg: ModelConfig, shape: ShapeSpec, mesh, *,
     specs_in = input_specs(cfg, shape)
     n_dev = mesh.devices.size
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step = make_train_step(cfg, tcfg,
                                    batch_axes=tuple(plan.batch_axes))
@@ -296,7 +291,7 @@ def optimizer_cost(cfg: ModelConfig, mesh, plan: ShardingPlan,
         new_p, new_o, m = adamw_update(tcfg.optimizer, grads, opt, params)
         return new_p, new_o, m["grad_norm"]
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(opt_only, in_shardings=(p_sh, o_sh, p_sh),
                          out_shardings=(p_sh, o_sh, None),
                          donate_argnums=(0, 1))

@@ -11,33 +11,18 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``jax.sharding.AxisType`` only exists in newer jax; older releases
-    default every axis to Auto anyway, so omit the kwarg there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (tests, examples, elastic re-meshes)."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh``: ``jax.sharding.set_mesh`` on
-    newer jax, the ``Mesh`` object's own context manager on older."""
-    setter = getattr(jax.sharding, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+    """Arbitrary mesh (tests, examples, elastic re-meshes).  Axes are
+    ``Auto``: GSPMD propagates shardings from the ``with_sharding_constraint``
+    hints the models place, as every caller here expects."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def pod_size(mesh) -> int:

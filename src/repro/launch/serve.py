@@ -2,7 +2,8 @@
 
 Loads (or random-initializes) a model and drives the batched serving
 engine over a synthetic request stream, reporting throughput and slot
-utilization.
+utilization.  ``serve(args)`` is the whole run; ``main`` parses the
+command line, calls it and prints its summary.
 """
 
 from __future__ import annotations
@@ -15,12 +16,13 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import place_compile_cache
 from repro.models import zoo
 from repro.serving import Request, ServeEngine
 from repro.train import CheckpointManager
 
 
-def main() -> None:
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b", choices=list(ARCH_IDS))
     ap.add_argument("--smoke", action="store_true")
@@ -28,11 +30,16 @@ def main() -> None:
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--max-new", type=int, default=24)
-    ap.add_argument("--prompt-len", type=int, default=16)
+    ap.add_argument("--prompt-len", type=int, default=16,
+                    help="prompts are seeded draws of half to this length")
     ap.add_argument("--ckpt-dir", default=None,
                     help="restore params from a training checkpoint")
-    args = ap.parse_args()
+    return ap
 
+
+def serve(args) -> tuple[dict, list[Request]]:
+    """Serve ``args.requests`` seeded requests; returns the summary and
+    the finished requests."""
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.ckpt_dir:
         restored = CheckpointManager(args.ckpt_dir).restore()
@@ -58,14 +65,20 @@ def main() -> None:
     done = engine.run()
     wall = time.perf_counter() - t0
     total_tokens = sum(len(r.output) for r in done)
-    print(json.dumps({
+    return {
         "arch": cfg.arch_id,
         "requests": len(done),
         "generated_tokens": total_tokens,
         "tokens_per_s": total_tokens / wall,
         "mean_slot_utilization": engine.mean_slot_utilization,
         "waves": len(engine.stats),
-    }, indent=1))
+        "wave_wall_s": [w.wall_s for w in engine.stats],
+    }, done
+
+
+def main() -> None:
+    place_compile_cache()
+    print(json.dumps(serve(parser().parse_args())[0], indent=1))
 
 
 if __name__ == "__main__":

@@ -3,9 +3,10 @@
 Runs real steps on the available devices (CPU smoke scale by default,
 TPU pods unchanged — the mesh adapts to jax.device_count()).  Wires every
 substrate piece: data pipeline + prefetch, sharded train step, async
-checkpointing, heartbeat, straggler monitor, recovery loop, and — when
---autotune is set — the paper-technique shard-degree autotuner before the
-steady-state phase (DESIGN.md §4).
+checkpointing, heartbeat, straggler monitor and the recovery loop.
+``train(args)`` is the whole run; ``main`` parses the command line, calls
+it and prints its summary.  A run in which a step never succeeded raises,
+so the command exits non-zero.
 """
 
 from __future__ import annotations
@@ -21,7 +22,8 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data import DataConfig, Prefetcher, make_source
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.compile_cache import place_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import zoo
 from repro.models.common import default_plan, replicated_plan
 from repro.optim import AdamWConfig
@@ -31,10 +33,29 @@ from repro.train import (CheckpointManager, Heartbeat, StragglerMonitor,
                          run_with_recovery, state_specs)
 
 
+def parser() -> argparse.ArgumentParser:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", default="olmo-1b", choices=list(ARCH_IDS))
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced config (CPU-runnable)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to this many layers (0: published)")
+    ap.add_argument("--steps", type=int, default=50)
+    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--seq", type=int, default=128)
+    ap.add_argument("--lr", type=float, default=3e-3)
+    ap.add_argument("--microbatches", type=int, default=1)
+    ap.add_argument("--no-remat", action="store_true")
+    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--save-every", type=int, default=20)
+    ap.add_argument("--resume", action="store_true")
+    return ap
+
+
 def build(args):
     cfg = get_config(args.arch, smoke=args.smoke)
-    if args.seq:
-        pass  # seq comes from data config
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     tcfg = TrainConfig(
         microbatches=args.microbatches,
         remat=not args.no_remat,
@@ -54,85 +75,100 @@ def build(args):
     return cfg, tcfg, mesh, plan
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="olmo-1b", choices=list(ARCH_IDS))
-    ap.add_argument("--smoke", action="store_true",
-                    help="reduced config (CPU-runnable)")
-    ap.add_argument("--steps", type=int, default=50)
-    ap.add_argument("--batch", type=int, default=8)
-    ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--lr", type=float, default=3e-3)
-    ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--no-remat", action="store_true")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
-    ap.add_argument("--save-every", type=int, default=20)
-    ap.add_argument("--resume", action="store_true")
-    args = ap.parse_args()
+def init_placed_state(cfg, tcfg, mesh, plan, key) -> dict:
+    """``init_state`` laid out on ``mesh`` per ``plan`` (call under
+    ``jax.set_mesh(mesh)``)."""
+    state = init_state(cfg, tcfg, key)
+    if len(mesh.devices.ravel()) > 1:
+        st_sh = named_sharding_tree(plan, mesh, state_specs(cfg, tcfg))
+        state = jax.tree.map(jax.device_put, state, st_sh)
+    return state
 
+
+def jit_train_step(cfg, tcfg, plan):
+    """The jitted step.  The state is donated, so the new params and
+    optimizer moments reuse the old ones' device memory."""
+    return jax.jit(make_train_step(
+        cfg, tcfg, batch_axes=tuple(plan.batch_axes) or None),
+        donate_argnums=0)
+
+
+def data_source(args, cfg):
+    return make_source(DataConfig(
+        seq_len=args.seq, global_batch=args.batch, vocab=cfg.vocab,
+        frontend_tokens=cfg.n_frontend_tokens
+        if zoo.needs_frontend(cfg) else 0,
+        d_model=cfg.d_model))
+
+
+def train(args) -> dict:
+    """Run ``args.steps`` steps and save a final checkpoint; returns the
+    summary with every step's loss."""
     cfg, tcfg, mesh, plan = build(args)
-    print(f"arch={cfg.arch_id} params={cfg.param_count():,} "
-          f"devices={jax.device_count()} mesh={dict(mesh.shape)}")
+    print(f"arch={cfg.arch_id} layers={cfg.n_layers} "
+          f"params={cfg.param_count():,} devices={jax.device_count()} "
+          f"mesh={dict(mesh.shape)}")
 
-    dcfg = DataConfig(seq_len=args.seq, global_batch=args.batch,
-                      vocab=cfg.vocab,
-                      frontend_tokens=cfg.n_frontend_tokens
-                      if zoo.needs_frontend(cfg) else 0,
-                      d_model=cfg.d_model)
-    source = make_source(dcfg)
-    prefetch = Prefetcher(source)
-
+    prefetch = Prefetcher(data_source(args, cfg))
     manager = CheckpointManager(args.ckpt_dir)
     heartbeat = Heartbeat(os.path.join(args.ckpt_dir, "heartbeat.json"))
     monitor = StragglerMonitor()
+    times: list[float] = []
+    losses: dict[int, float] = {}
 
-    with use_mesh(mesh):
-        state = init_state(cfg, tcfg, jax.random.PRNGKey(0))
-        if len(mesh.devices.ravel()) > 1:
-            st_sh = named_sharding_tree(plan, mesh, state_specs(cfg, tcfg))
-            state = jax.tree.map(jax.device_put, state, st_sh)
-        step_fn = jax.jit(make_train_step(
-            cfg, tcfg, batch_axes=tuple(plan.batch_axes) or None))
+    try:
+        with jax.set_mesh(mesh):
+            state = init_placed_state(cfg, tcfg, mesh, plan,
+                                      jax.random.PRNGKey(0))
+            step_fn = jit_train_step(cfg, tcfg, plan)
 
-        start = 0
-        if args.resume:
-            restored = manager.restore()
-            if restored:
-                state, extra, start = restored
-                print(f"resumed from step {start}")
+            if args.resume:
+                restored = manager.restore()
+                if restored:
+                    state, extra, start = restored
+                    print(f"resumed from step {start}")
 
-        times: list[float] = []
+            def wrapped(state, batch, step):
+                t0 = time.perf_counter()
+                jb = {k: jnp.asarray(v) for k, v in batch.items()}
+                state, metrics = step_fn(state, jb)
+                jax.block_until_ready(metrics["loss"])
+                dt = time.perf_counter() - t0
+                times.append(dt)
+                heartbeat.beat(step)
+                monitor.observe({"host0": dt})
+                return state, metrics
 
-        def wrapped(state, batch, step):
-            t0 = time.perf_counter()
-            jb = {k: jnp.asarray(v) for k, v in batch.items()}
-            state, metrics = step_fn(state, jb)
-            jax.block_until_ready(metrics["loss"])
-            dt = time.perf_counter() - t0
-            times.append(dt)
-            heartbeat.beat(step)
-            monitor.observe({"host0": dt})
-            return state, metrics
+            def on_metrics(step, metrics):
+                # keyed by step: a replayed step overwrites its own loss
+                losses[step] = float(metrics["loss"])
+                if step % 10 == 0 or step == args.steps - 1:
+                    print(f"step {step:5d} loss {losses[step]:.4f} "
+                          f"lr {float(metrics['lr']):.2e} "
+                          f"gnorm {float(metrics['grad_norm']):.3f} "
+                          f"dt {times[-1]*1e3:.0f}ms")
 
-        def on_metrics(step, metrics):
-            if step % 10 == 0 or step == args.steps - 1:
-                print(f"step {step:5d} loss {float(metrics['loss']):.4f} "
-                      f"lr {float(metrics['lr']):.2e} "
-                      f"gnorm {float(metrics['grad_norm']):.3f} "
-                      f"dt {times[-1]*1e3:.0f}ms")
-
-        state, stats = run_with_recovery(
-            wrapped, state, n_steps=args.steps,
-            save_every=args.save_every, manager=manager,
-            data_prefetch=prefetch, on_metrics=on_metrics)
-        manager.save(args.steps, state, extra={"final": True}, block=True)
-
-    prefetch.close()
-    print(json.dumps({
-        "steps": args.steps,
+            state, stats = run_with_recovery(
+                wrapped, state, n_steps=args.steps,
+                save_every=args.save_every, manager=manager,
+                data_prefetch=prefetch, on_metrics=on_metrics)
+            manager.save(args.steps, state, extra={"final": True},
+                         block=True)
+    finally:
+        prefetch.close()
+    return {
+        "arch": cfg.arch_id, "layers": cfg.n_layers, "steps": args.steps,
+        "batch": args.batch, "seq": args.seq,
+        "microbatches": tcfg.microbatches,
+        "losses": [losses[i] for i in sorted(losses)],
         "mean_step_ms": 1e3 * sum(times) / max(len(times), 1),
         "failures": stats.failures, "restores": stats.restores,
-    }))
+    }
+
+
+def main() -> None:
+    place_compile_cache()
+    print(json.dumps(train(parser().parse_args())))
 
 
 if __name__ == "__main__":

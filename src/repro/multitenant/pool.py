@@ -850,7 +850,14 @@ class RuntimePool:
         Tried by ``_admit`` BEFORE the running-work preemption path can
         act for the waiter: a free move always beats a priced one.  The
         victim must be strictly less late than the waiter (best-effort, or
-        more slack), so eviction chains terminate and never ping-pong."""
+        more slack), so eviction chains terminate and never ping-pong.
+
+        The waiter is probed with the victim already back in the queue, so
+        it is exactly the job the next ``pop_admissible`` hands out.  A
+        victim that re-enters AHEAD of every admissible waiter (its queue
+        key carries a deadline set after it was first enqueued) would
+        otherwise be re-admitted at once and evicted again at the same
+        instant, forever."""
         pol = self._preemption
         if not (pol.enabled and pol.evict_admitted) or not len(self.queue):
             return False
@@ -864,15 +871,17 @@ class RuntimePool:
                                  -(j.admit_time or 0.0), -j.jid))
         for victim in idle:
             rest = [j for j in active if j.jid != victim.jid]
+            self.queue.readmit(victim)
             waiter = self.queue.peek_admissible(rest, now)
-            if waiter is None:
-                continue           # evicting this one unblocks nothing
-            ws = self._root_slack(waiter, now)
-            if ws is None or ws > 0.0:
-                continue           # only an overdue SLO tenant justifies it
+            self.queue.remove(victim.jid)
+            # no waiter, or the victim itself: evicting unblocks nothing;
+            # only an overdue SLO tenant justifies the move, and never by
+            # bouncing a tenant just as late
+            ws = (None if waiter is None or waiter is victim
+                  else self._root_slack(waiter, now))
             vs = self._root_slack(victim, now)
-            if vs is not None and vs <= ws:
-                continue           # never bounce a tenant just as late
+            if ws is None or ws > 0.0 or (vs is not None and vs <= ws):
+                continue
             if self.sink.enabled:
                 self.sink.emit(TraceEvent(
                     ts=now, family=FAM_PREEMPTION, kind="evict",

@@ -81,6 +81,12 @@ class ServeEngine:
         self.queue.append(req)
 
     # ------------------------------------------------------------------
+    def _greedy(self, logits) -> np.ndarray:
+        """Greedy next tokens as a writable host array.  Logits are as
+        wide as ``cfg.padded_vocab``; the padding ids past ``cfg.vocab``
+        are no tokens and are never emitted."""
+        return np.array(jnp.argmax(logits[..., :self.cfg.vocab], axis=-1))
+
     def _batch_for(self, prompts: np.ndarray) -> dict:
         batch = {"tokens": jnp.asarray(prompts)}
         if zoo.needs_frontend(self.cfg):
@@ -113,7 +119,7 @@ class ServeEngine:
             logits, _ = zoo.prefill(self.cfg, self.params,
                                     self._batch_for(sub),
                                     zoo.cache_max_len(self.cfg, length))
-            exact = np.asarray(jnp.argmax(logits, axis=-1))
+            exact = self._greedy(logits)
             for j, i in enumerate(slots):
                 tokens[i] = exact[j]
 
@@ -128,7 +134,7 @@ class ServeEngine:
             self.cfg, min(self.max_len, plen + max_new))
         logits, cache = zoo.prefill(self.cfg, self.params,
                                     self._batch_for(prompts), cache_len)
-        tokens = np.array(jnp.argmax(logits, axis=-1))   # writable copy
+        tokens = self._greedy(logits)
         self._exact_short_logits(wave, plen, tokens)
         for i, r in enumerate(wave):
             if r.max_new_tokens <= 0:
@@ -148,7 +154,7 @@ class ServeEngine:
             logits, cache = self._decode(self.params, cache,
                                          jnp.asarray(tokens),
                                          jnp.asarray(pos))
-            tokens = np.asarray(jnp.argmax(logits, axis=-1))
+            tokens = self._greedy(logits)
             for i, r in enumerate(wave):
                 if r.done or len(r.output) >= r.max_new_tokens:
                     continue

@@ -14,6 +14,8 @@ import os
 import time
 from typing import Callable
 
+from jax.errors import JaxRuntimeError
+
 
 class Heartbeat:
     """Periodic liveness file: {step, time}.  A watchdog (or another
@@ -105,6 +107,23 @@ class StragglerMonitor:
                 if self._flags.get(h, 0) < self.consecutive_to_exclude]
 
 
+# status codes of a runtime error that the same program on the same state
+# raises again on replay: an allocation or compile that does not fit the
+# device, or a program the backend refuses
+_DETERMINISTIC_STATUS = ("RESOURCE_EXHAUSTED", "INVALID_ARGUMENT",
+                         "UNIMPLEMENTED", "FAILED_PRECONDITION")
+
+
+def _replay_can_fix(exc: BaseException) -> bool:
+    """Is ``exc`` a fault that restoring a checkpoint and replaying may get
+    past?  Runtime and I/O errors may be transient; a runtime error with a
+    deterministic status, and every other exception (raised while tracing
+    or lowering: shapes, dtypes, a kernel's tiling, a bug), are not."""
+    if isinstance(exc, JaxRuntimeError):
+        return not str(exc).startswith(_DETERMINISTIC_STATUS)
+    return isinstance(exc, (RuntimeError, OSError))
+
+
 @dataclasses.dataclass
 class RecoveryStats:
     failures: int = 0
@@ -119,9 +138,11 @@ def run_with_recovery(step_fn: Callable, state, *, n_steps: int,
                       ) -> tuple[object, RecoveryStats]:
     """Drive (state, batch) -> (state, metrics) with checkpoint/restore.
 
-    Any exception from step_fn triggers restore-from-latest and replay.
-    ``data_prefetch`` must expose .next()/.state()/.cursor and a
-    ``source.batch_at(step)`` for deterministic replay."""
+    An exception from step_fn that a replay may get past
+    (``_replay_can_fix``) triggers restore-from-latest and replay; any other
+    is re-raised at once.  ``data_prefetch`` must expose
+    .next()/.state()/.cursor and a ``source.batch_at(step)`` for
+    deterministic replay."""
     stats = RecoveryStats()
     step = 0
     while step < n_steps:
@@ -137,9 +158,9 @@ def run_with_recovery(step_fn: Callable, state, *, n_steps: int,
             if save_every and step % save_every == 0:
                 manager.save(step, state,
                              extra={"data_cursor": step})
-        except Exception:
+        except Exception as exc:
             stats.failures += 1
-            if stats.failures > max_failures:
+            if stats.failures > max_failures or not _replay_can_fix(exc):
                 raise
             restored = manager.restore()
             if restored is None:

@@ -270,6 +270,49 @@ def test_readmit_preserves_original_submit_order():
     assert q.pop_admissible([], 0.0) is b
 
 
+def _conv_dag(n, deps):
+    """Conv2D ops as ``tests/test_property.py::op_graphs`` draws them."""
+    b = GraphBuilder("rand")
+    elems = 32 * 8 * 8 * 64
+    for i in range(n):
+        b.add("Conv2D", (32, 8, 8, 64), flops=elems * 660.0,
+              bytes_moved=elems * 200.0, parallel_fraction=0.96,
+              deps=deps.get(i, []))
+    return b.build()
+
+
+def test_eviction_victim_requeued_ahead_of_waiter_is_not_evicted():
+    """Shrunk hypothesis draw of
+    ``test_economics_every_op_completes_exactly_once`` that never ended.
+
+    Deadlines are set after submission, so both tenants were queued as
+    best-effort.  An evicted victim re-enters under its real deadline,
+    ahead of the waiter it was evicted for; the next admission handed the
+    victim its slot back and the same eviction repeated at the same
+    instant forever.  The pool must now finish, every op exactly once."""
+    blocker = GraphBuilder("blocker")
+    prev = None
+    for _ in range(3):
+        prev = blocker.add("Huge", (512, 512, 64), flops=5e12,
+                           bytes_moved=1e9, working_set=1e9,
+                           deps=[prev] if prev is not None else [])
+    machine = SimMachine()
+    pool = RuntimePool(machine=machine, config=PoolConfig(
+        max_active=2, preemption=PreemptionPolicy(
+            enabled=True, max_victims=4, evict_admitted=True,
+            migration=True)))
+    jobs = [pool.submit(blocker.build(), name="blocker")]
+    for i, g in enumerate([_conv_dag(4, {1: [0]}), _conv_dag(2, {})],
+                          start=1):
+        t = 1e-4 * i
+        job = pool.submit(g, name=f"j{i}", submit_time=t)
+        job.deadline = t + max(job.cp.values(), default=0.0) * 1.5
+        jobs.append(job)
+    res = pool.run()
+    _assert_exactly_once(res, jobs)
+    _assert_no_oversubscription(machine, res)
+
+
 # ---------------------------------------------------------------------------
 # width migration
 # ---------------------------------------------------------------------------

@@ -96,6 +96,22 @@ class TestWkv6:
         np.testing.assert_allclose(np.asarray(out), np.asarray(oref),
                                    atol=5e-4, rtol=5e-3)
 
+    def test_software_exp_log_match_float64(self):
+        """The kernel's own exp/log (the chip's are approximations) stay
+        within a few f32 ulp over the ranges its decays use."""
+        from repro.kernels.rwkv6.kernel import _exp, _log
+        x = np.concatenate([np.linspace(-87, 0, 20001),
+                            -np.logspace(-8, 0, 101)]).astype(np.float32)
+        exact = np.exp(x.astype(np.float64))
+        got = np.asarray(jax.jit(_exp)(x), np.float64)
+        assert np.max(np.abs(got - exact) / exact) < 3e-7
+        assert float(_exp(jnp.float32(-jnp.inf))) == 0.0
+        w = np.concatenate([np.linspace(1e-4, 1, 20001),
+                            np.logspace(-37.9, 0, 1001)]).astype(np.float32)
+        exact = np.log(w.astype(np.float64))
+        got = np.asarray(jax.jit(_log)(w), np.float64)
+        np.testing.assert_allclose(got, exact, rtol=3e-7, atol=1e-9)
+
     def test_model_chunked_path_matches(self):
         """The jnp chunked path used by the model equals the oracle too."""
         from repro.models.layers import rwkv6_linear_attention

@@ -201,6 +201,56 @@ class TestFault:
         assert int(state["n"]) == 10 + stats.steps_replayed \
             or int(state["n"]) == 10
 
+    @pytest.mark.parametrize("exc", [
+        jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: Error allocating device buffer"),
+        jax.errors.JaxRuntimeError(
+            "INVALID_ARGUMENT: the backend refused the program"),
+        ValueError("block shape not divisible by (8, 128)"),
+    ], ids=["hbm_oom", "compile_refused", "lowering_error"])
+    def test_recovery_reraises_what_replay_cannot_fix(self, exc):
+        calls = {"n": 0}
+
+        def step_fn(state, batch, step):
+            calls["n"] += 1
+            raise exc
+
+        with tempfile.TemporaryDirectory() as d:
+            with pytest.raises(type(exc)):
+                run_with_recovery(step_fn, {}, n_steps=3, save_every=1,
+                                  manager=CheckpointManager(d))
+        assert calls["n"] == 1
+
+
+def test_train_launcher_runs_and_reports_every_loss():
+    from repro.launch.train import parser, train
+    with tempfile.TemporaryDirectory() as d:
+        args = parser().parse_args([
+            "--smoke", "--layers", "1", "--steps", "3", "--batch", "2",
+            "--seq", "16", "--save-every", "0", "--ckpt-dir", d])
+        res = train(args)
+        assert CheckpointManager(d).latest_step() == 3
+    assert res["layers"] == 1 and res["failures"] == 0
+    assert len(res["losses"]) == 3
+    assert all(np.isfinite(res["losses"]))
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache goes
+    to the checkout's fixed .jax_cache."""
+    from repro.launch.compile_cache import REPO_CACHE_DIR, place_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert place_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert place_compile_cache() == str(REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+        assert (REPO_CACHE_DIR.parent / "pyproject.toml").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
 
 class TestServing:
     def _engine(self, n_slots=3):
