@@ -3,7 +3,7 @@
 Runs real steps on the available devices (CPU smoke scale by default,
 TPU pods unchanged — the mesh adapts to jax.device_count()).  Wires every
 substrate piece: data pipeline + prefetch, sharded train step, async
-checkpointing, heartbeat, straggler monitor and the recovery loop.
+checkpointing, heartbeat and the recovery loop.
 ``train(args)`` is the whole run; ``main`` parses the command line, calls
 it and prints its summary.  A run in which a step never succeeded raises,
 so the command exits non-zero.
@@ -28,9 +28,9 @@ from repro.models import zoo
 from repro.models.common import default_plan, replicated_plan
 from repro.optim import AdamWConfig
 from repro.sharding import named_sharding_tree
-from repro.train import (CheckpointManager, Heartbeat, StragglerMonitor,
-                         TrainConfig, init_state, make_train_step,
-                         run_with_recovery, state_specs)
+from repro.train import (CheckpointManager, Heartbeat, TrainConfig,
+                         init_state, make_train_step, run_with_recovery,
+                         state_specs)
 
 
 def parser() -> argparse.ArgumentParser:
@@ -112,7 +112,6 @@ def train(args) -> dict:
     prefetch = Prefetcher(data_source(args, cfg))
     manager = CheckpointManager(args.ckpt_dir)
     heartbeat = Heartbeat(os.path.join(args.ckpt_dir, "heartbeat.json"))
-    monitor = StragglerMonitor()
     times: list[float] = []
     losses: dict[int, float] = {}
 
@@ -136,7 +135,6 @@ def train(args) -> dict:
                 dt = time.perf_counter() - t0
                 times.append(dt)
                 heartbeat.beat(step)
-                monitor.observe({"host0": dt})
                 return state, metrics
 
             def on_metrics(step, metrics):
@@ -163,6 +161,7 @@ def train(args) -> dict:
         "losses": [losses[i] for i in sorted(losses)],
         "mean_step_ms": 1e3 * sum(times) / max(len(times), 1),
         "failures": stats.failures, "restores": stats.restores,
+        "compiles": stats.compiles,
     }
 
 

@@ -106,43 +106,45 @@ def _layer(cfg: ModelConfig, lp: dict, x: jax.Array,
     dt = x.dtype
     b, s, d = x.shape
     hd = cfg.hd
-    h = _norm(cfg, x, lp["attn_norm"])
-    if cfg.seq_axes:
-        h = L.constrain_batch(h, cfg.batch_axes, ())   # gather into TP
-    q = jnp.einsum("bsd,dh->bsh", h, lp["wq"].astype(dt)
-                   ).reshape(b, s, cfg.n_heads, hd)
-    k = jnp.einsum("bsd,dh->bsh", h, lp["wk"].astype(dt)
-                   ).reshape(b, s, cfg.n_kv_heads, hd)
-    v = jnp.einsum("bsd,dh->bsh", h, lp["wv"].astype(dt)
-                   ).reshape(b, s, cfg.n_kv_heads, hd)
-    q = L.apply_rope(q, cos, sin)
-    k = L.apply_rope(k, cos, sin)
-    o = L.attention(q, k, v, causal=True, window=cfg.window,
-                    unroll=cfg.scan_unroll)
-    o = jnp.einsum("bsh,hd->bsd", o.reshape(b, s, cfg.n_heads * hd),
-                   lp["wo"].astype(dt))
-    if cfg.seq_axes:
-        o = L.seq_boundary(o, cfg.batch_axes, cfg.seq_axes)  # RS back
-    x = x + o
+    with jax.named_scope("attention"):
+        h = _norm(cfg, x, lp["attn_norm"])
+        if cfg.seq_axes:
+            h = L.constrain_batch(h, cfg.batch_axes, ())   # gather into TP
+        q = jnp.einsum("bsd,dh->bsh", h, lp["wq"].astype(dt)
+                       ).reshape(b, s, cfg.n_heads, hd)
+        k = jnp.einsum("bsd,dh->bsh", h, lp["wk"].astype(dt)
+                       ).reshape(b, s, cfg.n_kv_heads, hd)
+        v = jnp.einsum("bsd,dh->bsh", h, lp["wv"].astype(dt)
+                       ).reshape(b, s, cfg.n_kv_heads, hd)
+        q = L.apply_rope(q, cos, sin)
+        k = L.apply_rope(k, cos, sin)
+        o = L.attention(q, k, v, causal=True, window=cfg.window,
+                        unroll=cfg.scan_unroll)
+        o = jnp.einsum("bsh,hd->bsd", o.reshape(b, s, cfg.n_heads * hd),
+                       lp["wo"].astype(dt))
+        if cfg.seq_axes:
+            o = L.seq_boundary(o, cfg.batch_axes, cfg.seq_axes)  # RS back
+        x = x + o
 
-    h2 = _norm(cfg, x, lp["mlp_norm"])
-    if cfg.seq_axes:
-        h2 = L.constrain_batch(h2, cfg.batch_axes, ())
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.moe_experts:
-        moe_out, aux = L.moe_block(
-            lp, h2, n_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
-            capacity_factor=cfg.moe_capacity_factor)
+    with jax.named_scope("mlp"):
+        h2 = _norm(cfg, x, lp["mlp_norm"])
         if cfg.seq_axes:
-            moe_out = L.seq_boundary(moe_out, cfg.batch_axes,
-                                     cfg.seq_axes)
-        x = x + moe_out
-    else:
-        m = (L.mlp_swiglu(lp, h2) if cfg.act == "swiglu"
-             else L.mlp_gelu(lp, h2))
-        if cfg.seq_axes:
-            m = L.seq_boundary(m, cfg.batch_axes, cfg.seq_axes)
-        x = x + m
+            h2 = L.constrain_batch(h2, cfg.batch_axes, ())
+        aux = jnp.zeros((), jnp.float32)
+        if cfg.moe_experts:
+            moe_out, aux = L.moe_block(
+                lp, h2, n_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
+                capacity_factor=cfg.moe_capacity_factor)
+            if cfg.seq_axes:
+                moe_out = L.seq_boundary(moe_out, cfg.batch_axes,
+                                         cfg.seq_axes)
+            x = x + moe_out
+        else:
+            m = (L.mlp_swiglu(lp, h2) if cfg.act == "swiglu"
+                 else L.mlp_gelu(lp, h2))
+            if cfg.seq_axes:
+                m = L.seq_boundary(m, cfg.batch_axes, cfg.seq_axes)
+            x = x + m
     return x, (k, v, aux)
 
 
@@ -155,7 +157,8 @@ def forward(cfg: ModelConfig, params: dict, batch: dict,
     tokens = batch["tokens"]
     b, s = tokens.shape
     dt = cfg.activation_dtype
-    x = params["embed"]["table"].astype(dt)[tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"]["table"].astype(dt)[tokens]
     pos = jnp.arange(s)
     cos, sin = L.rope_angles(pos, cfg.hd, cfg.rope_theta)
 
@@ -168,14 +171,15 @@ def forward(cfg: ModelConfig, params: dict, batch: dict,
     x, ys = jax.lax.scan(L.maybe_remat(body, cfg.remat), x,
                          params["layers"], unroll=cfg.scan_unroll)
     aux = jnp.sum(ys[-1])
-    x = _norm(cfg, x, params["final_norm"])
-    if last_only:
-        x = x[:, -1:]
-    unemb = (params["embed"]["table"].astype(dt).T if cfg.tie_embeddings
-             else params["unembed"].astype(dt))
-    logits = jnp.einsum("bsd,dv->bsv", x, unemb)
-    if cfg.logit_softcap:
-        logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
+    with jax.named_scope("head"):
+        x = _norm(cfg, x, params["final_norm"])
+        if last_only:
+            x = x[:, -1:]
+        unemb = (params["embed"]["table"].astype(dt).T if cfg.tie_embeddings
+                 else params["unembed"].astype(dt))
+        logits = jnp.einsum("bsd,dv->bsv", x, unemb)
+        if cfg.logit_softcap:
+            logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
     if collect_cache:
         return logits, aux, (ys[0], ys[1])   # (L,S,B,K,hd) each
     return logits, aux

@@ -83,17 +83,18 @@ def loss_fn(cfg: ModelConfig, params: dict, batch: dict,
     rematerialization), while the one-hot einsum partitions cleanly
     (local partial sum + small all-reduce)."""
     logits, aux = forward(cfg, params, batch)
-    targets = batch["targets"]
-    lf = logits.astype(jnp.float32)
-    lse = jax.scipy.special.logsumexp(lf, axis=-1)
-    onehot = jax.nn.one_hot(targets, lf.shape[-1], dtype=lf.dtype)
-    true_logit = jnp.einsum("bsv,bsv->bs", lf, onehot)
-    nll = lse - true_logit
-    mask = batch.get("mask")
-    if mask is not None:
-        nll = nll * mask
-        denom = jnp.maximum(jnp.sum(mask), 1.0)
-    else:
-        denom = nll.size
-    loss = jnp.sum(nll) / denom
+    with jax.named_scope("head"):
+        targets = batch["targets"]
+        lf = logits.astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(lf, axis=-1)
+        onehot = jax.nn.one_hot(targets, lf.shape[-1], dtype=lf.dtype)
+        true_logit = jnp.einsum("bsv,bsv->bs", lf, onehot)
+        nll = lse - true_logit
+        mask = batch.get("mask")
+        if mask is not None:
+            nll = nll * mask
+            denom = jnp.maximum(jnp.sum(mask), 1.0)
+        else:
+            denom = nll.size
+        loss = jnp.sum(nll) / denom
     return loss + aux_weight * aux, {"ce": loss, "aux": aux}

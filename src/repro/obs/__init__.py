@@ -2,14 +2,16 @@
 
 The telemetry subsystem every scheduling layer emits into — see
 ``repro.obs.trace`` for the ``TraceSink`` seam and the six decision-event
-families, ``repro.obs.metrics`` for the registry, ``repro.obs.perfetto``
-for Chrome-trace/Perfetto export, ``repro.obs.log`` for the shared
-``repro`` logger.  This package never imports the schedulers (they import
-us), so any later subsystem can emit into it without cycles.
+families, ``repro.obs.metrics`` for the registry and the process's
+compile counter, ``repro.obs.perfetto`` for Chrome-trace/Perfetto
+export, ``repro.obs.log`` for the shared ``repro`` logger.  This package
+never imports the schedulers (they import us), so any later subsystem
+can emit into it without cycles.
 """
 
 from repro.obs.log import configure_logging, get_logger
-from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
+from repro.obs.metrics import (CompileCounter, Counter, Gauge, Histogram,
+                               MetricsRegistry, compile_counter,
                                metrics_from_events, pool_metrics,
                                slowdown_metrics)
 from repro.obs.perfetto import (cluster_trace, export_cluster_trace,
@@ -25,7 +27,8 @@ __all__ = [
     "FAM_STRATEGY", "FAMILIES", "NULL_SINK", "NullSink",
     "RecordingSink",
     "TraceEvent", "TraceSink",
-    "Counter", "Gauge", "Histogram", "MetricsRegistry",
+    "CompileCounter", "Counter", "Gauge", "Histogram", "MetricsRegistry",
+    "compile_counter",
     "metrics_from_events", "pool_metrics", "slowdown_metrics",
     "cluster_trace", "export_cluster_trace",
     "export_pool_trace", "pool_trace", "write_trace",

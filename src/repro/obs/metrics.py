@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import threading
 from typing import Iterable
 
 from repro.obs.trace import (FAM_ADMISSION, FAM_CLUSTER, FAM_PLACEMENT,
@@ -97,6 +98,76 @@ class Histogram:
     @property
     def max(self) -> float:
         return max(self.values) if self.values else 0.0
+
+
+# jax.monitoring events of one compilation, in the order they happen: the
+# trace to a jaxpr, its lowering to a module, and the backend compile
+# (which, on a persistent compile-cache hit, is the load from the cache)
+JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+COMPILE_EVENTS = (JAXPR_TRACE_EVENT,
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                  "/jax/core/compile/backend_compile_duration")
+
+
+class CompileCounter:
+    """JAX compilations of this process, heard through ``jax.monitoring``.
+
+    ``traces`` counts the traces of a function to a jaxpr, the first phase
+    of every compilation (a call that hits the in-memory cache makes none);
+    ``seconds`` sums the wall time of tracing, lowering and compiling or
+    loading from the persistent cache.  A span that falls inside another of
+    the same process (a jitted function traced inside an outer trace) is
+    counted once, as part of the outer one.  Listening starts with
+    ``start`` and ends with ``stop``."""
+
+    def __init__(self) -> None:
+        self.traces = Counter()
+        self.seconds = Counter()
+        self._lock = threading.Lock()
+        self._spans: list[tuple[float, float, bool]] = []  # outermost
+        self._listening = False
+
+    def _on_span(self, event: str, start: float, end: float,
+                 **_: object) -> None:
+        if event not in COMPILE_EVENTS:
+            return
+        with self._lock:
+            # spans arrive as they end, so one nested in this span arrived
+            # earlier and started no earlier
+            while self._spans and self._spans[-1][0] >= start:
+                s, e, is_trace = self._spans.pop()
+                self.seconds.inc(-(e - s))
+                self.traces.inc(-float(is_trace))
+            is_trace = event == JAXPR_TRACE_EVENT
+            self._spans.append((start, end, is_trace))
+            self.seconds.inc(end - start)
+            self.traces.inc(float(is_trace))
+
+    def start(self) -> "CompileCounter":
+        from jax import monitoring
+        if not self._listening:
+            monitoring.register_event_time_span_listener(self._on_span)
+            self._listening = True
+        return self
+
+    def stop(self) -> None:
+        from jax import monitoring
+        if self._listening:
+            monitoring.unregister_event_time_span_listener(self._on_span)
+            self._listening = False
+
+    def read(self) -> tuple[int, float]:
+        """(traces, seconds) so far."""
+        with self._lock:
+            return int(self.traces.value), self.seconds.value
+
+
+_PROCESS_COMPILES = CompileCounter()
+
+
+def compile_counter() -> CompileCounter:
+    """The process-wide counter, listening from its first use on."""
+    return _PROCESS_COMPILES.start()
 
 
 class MetricsRegistry:

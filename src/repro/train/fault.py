@@ -14,7 +14,15 @@ import os
 import time
 from typing import Callable
 
+import jax
 from jax.errors import JaxRuntimeError
+
+from repro.obs import compile_counter
+
+# host spans each iteration of ``run_with_recovery`` writes into a
+# profiler trace, on the clock of the device planes
+STEP_SPAN = "repro.step"
+DATA_SPAN = "repro.data"
 
 
 class Heartbeat:
@@ -129,6 +137,7 @@ class RecoveryStats:
     failures: int = 0
     restores: int = 0
     steps_replayed: int = 0
+    compiles: int = 0          # traces to a jaxpr inside the loop
 
 
 def run_with_recovery(step_fn: Callable, state, *, n_steps: int,
@@ -142,22 +151,28 @@ def run_with_recovery(step_fn: Callable, state, *, n_steps: int,
     (``_replay_can_fix``) triggers restore-from-latest and replay; any other
     is re-raised at once.  ``data_prefetch`` must expose
     .next()/.state()/.cursor and a ``source.batch_at(step)`` for
-    deterministic replay."""
+    deterministic replay.  Each iteration is a ``repro.step`` span in a
+    profiler trace, and its ``batch_at`` a ``repro.data`` span inside it;
+    the compilations the loop causes are counted into the stats."""
     stats = RecoveryStats()
+    compiles = compile_counter()
+    traces0, _ = compiles.read()
     step = 0
     while step < n_steps:
         try:
-            if data_prefetch is not None:
-                batch = data_prefetch.source.batch_at(step)
-            else:
-                batch = None
-            state, metrics = step_fn(state, batch, step)
-            if on_metrics is not None:
-                on_metrics(step, metrics)
-            step += 1
-            if save_every and step % save_every == 0:
-                manager.save(step, state,
-                             extra={"data_cursor": step})
+            with jax.profiler.StepTraceAnnotation(STEP_SPAN, step_num=step):
+                if data_prefetch is not None:
+                    with jax.profiler.TraceAnnotation(DATA_SPAN):
+                        batch = data_prefetch.source.batch_at(step)
+                else:
+                    batch = None
+                state, metrics = step_fn(state, batch, step)
+                if on_metrics is not None:
+                    on_metrics(step, metrics)
+                step += 1
+                if save_every and step % save_every == 0:
+                    manager.save(step, state,
+                                 extra={"data_cursor": step})
         except Exception as exc:
             stats.failures += 1
             if stats.failures > max_failures or not _replay_can_fix(exc):
@@ -173,4 +188,5 @@ def run_with_recovery(step_fn: Callable, state, *, n_steps: int,
             stats.steps_replayed += max(0, step - ck_step)
             step = ck_step
     manager.wait()
+    stats.compiles = compiles.read()[0] - traces0
     return state, stats

@@ -141,8 +141,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
             grads, new_error, cm = compress(
                 tcfg.compression, grads, state["error"])
             metrics.update(cm)
-        new_params, new_opt, om = adamw_update(
-            tcfg.optimizer, grads, state["opt"], params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, om = adamw_update(
+                tcfg.optimizer, grads, state["opt"], params)
         metrics.update(om)
         new_state = {"params": new_params, "opt": new_opt}
         if "error" in state:
