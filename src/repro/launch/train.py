@@ -26,6 +26,7 @@ from repro.launch.compile_cache import place_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import zoo
 from repro.models.common import default_plan, replicated_plan
+from repro.obs import attention_paths
 from repro.optim import AdamWConfig
 from repro.sharding import named_sharding_tree
 from repro.train import (CheckpointManager, Heartbeat, TrainConfig,
@@ -114,6 +115,7 @@ def train(args) -> dict:
     heartbeat = Heartbeat(os.path.join(args.ckpt_dir, "heartbeat.json"))
     times: list[float] = []
     losses: dict[int, float] = {}
+    paths0 = attention_paths().snapshot()
 
     try:
         with jax.set_mesh(mesh):
@@ -162,6 +164,9 @@ def train(args) -> dict:
         "mean_step_ms": 1e3 * sum(times) / max(len(times), 1),
         "failures": stats.failures, "restores": stats.restores,
         "compiles": stats.compiles,
+        "attention_paths": {
+            path: int(n - paths0.get(path, 0))
+            for path, n in attention_paths().snapshot().items()},
     }
 
 

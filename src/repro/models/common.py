@@ -88,6 +88,11 @@ class ModelConfig:
     logit_softcap: float = 0.0
 
     @property
+    def sharded(self) -> bool:
+        """The activations are partitioned over a mesh."""
+        return bool(self.batch_axes or self.seq_axes)
+
+    @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
 

@@ -1,11 +1,17 @@
 """Shared neural-net layers: norms, RoPE, attention flavors, MLPs, MoE,
 gated linear recurrences (RG-LRU, RWKV6).
 
-Everything is a pure function of (params subtree, activations).  Attention
-defaults to the jnp reference math (what the dry-run lowers — XLA fuses it
-adequately for roofline purposes); the Pallas flash kernel in
-``repro.kernels.flash_attention`` is the TPU-target drop-in and is
-validated against the same math in interpret mode.
+Everything is a pure function of (params subtree, activations).
+
+Self-attention (``attention``) runs the Pallas flash kernel of
+``repro.kernels.flash_attention`` (forward and backward, no (S, S) score
+tensor in HBM) when the program is traced for a TPU, the call is prefill
+or training (``q_offset`` 0, as many queries as keys), there is no logit
+softcap, the kernel's blocks tile S, and the activations are not
+partitioned over a mesh.  Every other call keeps the jnp math below:
+the CPU (tests, the dry-run), decode, cross-attention, softcapped models
+and sharded plans.  ``attention_path`` makes that choice, and
+``repro.obs.attention_paths()`` counts it at trace time.
 """
 
 from __future__ import annotations
@@ -15,6 +21,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.flash_attention.ops import block_size, flash_attention
+from repro.obs import attention_paths
 
 
 def maybe_remat(body, remat: str):
@@ -178,18 +187,57 @@ _CHUNK_THRESHOLD = 1 << 26
 _Q_CHUNK = 1024
 
 
+def _platform() -> str:
+    """The platform a trace is lowered for: the default backend's."""
+    return jax.default_backend()
+
+
+def attention_path(q_shape: tuple, k_shape: tuple, *, q_offset=0,
+                   softcap: float = 0.0, sharded: bool = False,
+                   platform: str) -> str:
+    """``"flash"`` where the Pallas kernel computes this self-attention,
+    else ``"dense"`` (the jnp math, chunked over q for long sequences).
+
+    The kernel under ``shard_map`` for partitioned activations waits for a
+    sharded cell to measure it on (PERF.md §7); until then a mesh plan
+    keeps the jnp math, which GSPMD partitions."""
+    sq = q_shape[1]
+    if (platform != "tpu" or sharded or softcap
+            or not isinstance(q_offset, int) or q_offset
+            or sq != k_shape[1] or block_size(sq) is None):
+        return "dense"
+    return "flash"
+
+
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: int | None = None,
               q_offset: int = 0, softcap: float = 0.0,
-              unroll: bool = False) -> jax.Array:
-    """q: (B,Sq,H,D), k/v: (B,Sk,K,D) with H % K == 0.  Returns (B,Sq,H,D).
+              unroll: bool = False, sharded: bool = False) -> jax.Array:
+    """Self-attention. q: (B,Sq,H,D), k/v: (B,Sk,K,D) with H % K == 0.
+    Returns (B,Sq,H,D).
 
     ``q_offset``: absolute position of q[0] relative to k[0] (prefill=0,
     decode=Sk-1).  ``window``: keys further than ``window`` behind the
-    query are masked (sliding-window / local attention).  Long sequences
-    are processed in q-chunks so the score matrix transient stays bounded
-    (each chunk still scores the full key range; the causal half-waste is
-    what the Pallas kernel's block skipping removes on TPU)."""
+    query are masked (sliding-window / local attention).  ``sharded``:
+    the activations are partitioned over a mesh (the config's
+    ``batch_axes`` or ``seq_axes``).  ``attention_path`` picks the flash
+    kernel or the jnp math."""
+    path = attention_path(q.shape, k.shape, q_offset=q_offset,
+                          softcap=softcap, sharded=sharded,
+                          platform=_platform())
+    attention_paths().counter(path).inc()
+    if path == "flash":
+        with jax.named_scope("flash"):
+            return flash_attention(q, k, v, causal=causal, window=window)
+    return _attention_jnp(q, k, v, causal=causal, window=window,
+                          q_offset=q_offset, softcap=softcap, unroll=unroll)
+
+
+def _attention_jnp(q, k, v, *, causal, window, q_offset, softcap, unroll):
+    """Long sequences are processed in q-chunks so the score matrix
+    transient stays bounded (each chunk still scores the full key range;
+    the causal half-waste is what the flash kernel's block skipping
+    removes on TPU)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     if sq * sk < _CHUNK_THRESHOLD or sq <= _Q_CHUNK or sq % _Q_CHUNK:
@@ -210,8 +258,11 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def cross_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    softcap: float = 0.0) -> jax.Array:
-    return attention(q, k, v, causal=False, window=None, softcap=softcap)
+                    softcap: float = 0.0, unroll: bool = False) -> jax.Array:
+    """Queries of one sequence over keys of another: always the jnp math."""
+    attention_paths().counter("dense").inc()
+    return _attention_jnp(q, k, v, causal=False, window=None, q_offset=0,
+                          softcap=softcap, unroll=unroll)
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,

@@ -119,7 +119,7 @@ def _layer(cfg: ModelConfig, lp: dict, x: jax.Array,
         q = L.apply_rope(q, cos, sin)
         k = L.apply_rope(k, cos, sin)
         o = L.attention(q, k, v, causal=True, window=cfg.window,
-                        unroll=cfg.scan_unroll)
+                        unroll=cfg.scan_unroll, sharded=cfg.sharded)
         o = jnp.einsum("bsh,hd->bsd", o.reshape(b, s, cfg.n_heads * hd),
                        lp["wo"].astype(dt))
         if cfg.seq_axes:

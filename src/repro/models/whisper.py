@@ -82,15 +82,21 @@ def _proj_heads(x, w, b, s, nh, hd):
                       ).reshape(b, s, nh, hd)
 
 
-def _mha(cfg, lp, xq, xkv, causal):
+def _mha(cfg, lp, xq, xkv=None, causal=False):
+    """Self-attention over ``xq``, or cross-attention over ``xkv``."""
     dt = xq.dtype
     b, sq, _ = xq.shape
-    sk = xkv.shape[1]
+    src = xq if xkv is None else xkv
+    sk = src.shape[1]
     hd = cfg.hd
     q = _proj_heads(xq, lp["wq"], b, sq, cfg.n_heads, hd)
-    k = _proj_heads(xkv, lp["wk"], b, sk, cfg.n_kv_heads, hd)
-    v = _proj_heads(xkv, lp["wv"], b, sk, cfg.n_kv_heads, hd)
-    o = L.attention(q, k, v, causal=causal, unroll=cfg.scan_unroll)
+    k = _proj_heads(src, lp["wk"], b, sk, cfg.n_kv_heads, hd)
+    v = _proj_heads(src, lp["wv"], b, sk, cfg.n_kv_heads, hd)
+    if xkv is None:
+        o = L.attention(q, k, v, causal=causal, unroll=cfg.scan_unroll,
+                        sharded=cfg.sharded)
+    else:
+        o = L.cross_attention(q, k, v, unroll=cfg.scan_unroll)
     return jnp.einsum("bsh,hd->bsd", o.reshape(b, sq, cfg.n_heads * hd),
                       lp["wo"].astype(dt)), k, v
 
@@ -109,7 +115,7 @@ def encode(cfg: ModelConfig, params: dict, frames: jax.Array) -> jax.Array:
     def body(carry, lp):
         y = L.constrain_batch(carry, cfg.batch_axes, cfg.seq_axes)
         h = L.layer_norm(y, lp["attn_norm"], None)
-        o, _, _ = _mha(cfg, lp, h, h, causal=False)
+        o, _, _ = _mha(cfg, lp, h, causal=False)
         y = y + o
         h2 = L.layer_norm(y, lp["mlp_norm"], None)
         y = y + L.mlp_gelu(lp, h2)
@@ -123,10 +129,10 @@ def encode(cfg: ModelConfig, params: dict, frames: jax.Array) -> jax.Array:
 def _dec_layer(cfg, lp, x, enc, cos_sin=None):
     x = L.constrain_batch(x, cfg.batch_axes, cfg.seq_axes)
     h = L.layer_norm(x, lp["self_norm"], None)
-    o, k, v = _mha(cfg, lp["self"], h, h, causal=True)
+    o, k, v = _mha(cfg, lp["self"], h, causal=True)
     x = x + o
     h2 = L.layer_norm(x, lp["cross_norm"], None)
-    oc, xk, xv = _mha(cfg, lp["cross"], h2, enc, causal=False)
+    oc, xk, xv = _mha(cfg, lp["cross"], h2, enc)
     x = x + oc
     h3 = L.layer_norm(x, lp["mlp_norm"], None)
     x = x + L.mlp_gelu(lp, h3)

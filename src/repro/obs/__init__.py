@@ -3,15 +3,16 @@
 The telemetry subsystem every scheduling layer emits into — see
 ``repro.obs.trace`` for the ``TraceSink`` seam and the six decision-event
 families, ``repro.obs.metrics`` for the registry and the process's
-compile counter, ``repro.obs.perfetto`` for Chrome-trace/Perfetto
-export, ``repro.obs.log`` for the shared ``repro`` logger.  This package
-never imports the schedulers (they import us), so any later subsystem
-can emit into it without cycles.
+compile and attention-path counters, ``repro.obs.perfetto`` for
+Chrome-trace/Perfetto export, ``repro.obs.log`` for the shared ``repro``
+logger.  This package never imports the schedulers (they import us), so
+any later subsystem can emit into it without cycles.
 """
 
 from repro.obs.log import configure_logging, get_logger
 from repro.obs.metrics import (CompileCounter, Counter, Gauge, Histogram,
-                               MetricsRegistry, compile_counter,
+                               MetricsRegistry, attention_paths,
+                               compile_counter,
                                metrics_from_events, pool_metrics,
                                slowdown_metrics)
 from repro.obs.perfetto import (cluster_trace, export_cluster_trace,
@@ -28,7 +29,7 @@ __all__ = [
     "RecordingSink",
     "TraceEvent", "TraceSink",
     "CompileCounter", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "compile_counter",
+    "attention_paths", "compile_counter",
     "metrics_from_events", "pool_metrics", "slowdown_metrics",
     "cluster_trace", "export_cluster_trace",
     "export_pool_trace", "pool_trace", "write_trace",

@@ -211,6 +211,17 @@ class MetricsRegistry:
         return out
 
 
+_ATTENTION_PATHS = MetricsRegistry()
+
+
+def attention_paths() -> MetricsRegistry:
+    """The process-wide count of the path each self- or cross-attention
+    call took: one counter per path (``"flash"`` kernel or ``"dense"`` jnp
+    math), counted when the call runs, which inside a jitted program is
+    once per trace."""
+    return _ATTENTION_PATHS
+
+
 # ---------------------------------------------------------------------------
 # PoolResult -> registry (the path RuntimePool.run always takes)
 # ---------------------------------------------------------------------------
@@ -328,6 +339,9 @@ def metrics_from_events(events: Iterable[TraceEvent]) -> MetricsRegistry:
     either an emit site is missing or one is lying — both are bugs the
     test suite exists to catch."""
     reg = MetricsRegistry()
+    # a run that revokes nothing has no refund event; pool_metrics reports
+    # its restart waste as 0.0 all the same
+    reg.counter("pool.restart_waste_core_s")
     service: dict[int, float] = {}
     priority: dict[int, float] = {}
     makespan = 0.0

@@ -1,10 +1,14 @@
-"""Per-kernel shape/dtype sweeps vs. the pure-jnp oracles (interpret mode)."""
+"""Per-kernel shape/dtype sweeps vs. the pure-jnp oracles (interpret mode),
+and the models' choice between the flash kernel and the jnp attention."""
+
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.flash_attention import kernel as fa_kernel
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.rglru.ops import rglru
@@ -58,6 +62,155 @@ class TestFlashAttention:
         ref = attention_ref(q, k, v, causal=False)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
+
+
+    @pytest.mark.parametrize("causal,window,bq,bk", [
+        (True, None, 64, 64),
+        (True, None, 128, 64),
+        (True, 80, 64, 128),
+        (False, None, 128, 128),
+    ])
+    def test_forward_logsumexp(self, causal, window, bq, bk):
+        ks = jax.random.split(KEY, 3)
+        q = jax.random.normal(ks[0], (1, 4, 256, 64))
+        k = jax.random.normal(ks[1], (1, 2, 256, 64))
+        v = jax.random.normal(ks[2], (1, 2, 256, 64))
+        o, lse = fa_kernel.flash_forward(q, k, v, causal=causal,
+                                         window=window, block_q=bq,
+                                         block_k=bk, interpret=True)
+        kr = jnp.repeat(k, 2, axis=1)
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, kr) / math.sqrt(64)
+        pos = jnp.arange(256)
+        keep = jnp.ones((256, 256), bool)
+        if causal:
+            keep &= pos[None, :] <= pos[:, None]
+        if window is not None:
+            keep &= pos[None, :] > pos[:, None] - window
+        want = jax.nn.logsumexp(jnp.where(keep, logits, -jnp.inf), axis=-1)
+        assert lse.shape == (1, 4, 256, fa_kernel.LANES)
+        np.testing.assert_allclose(np.asarray(lse),
+                                   np.broadcast_to(want[..., None],
+                                                   lse.shape),
+                                   atol=2e-5, rtol=2e-5)
+        ref = attention_ref(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)),
+                            causal=causal, window=window)
+        np.testing.assert_allclose(np.asarray(o),
+                                   np.asarray(jnp.swapaxes(ref, 1, 2)),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("b,h,kh,d,window,bq,bk,dtype", [
+        (2, 4, 2, 64, None, 64, 64, jnp.float32),       # GQA group 2
+        (1, 2, 2, 128, None, 128, 64, jnp.float32),     # rectangular
+        (1, 4, 2, 64, 64, 64, 64, jnp.float32),         # window: k blocks
+        (1, 4, 4, 64, 96, 128, 64, jnp.float32),        # skipped both sides
+        (2, 4, 2, 64, None, 64, 128, jnp.bfloat16),
+        (1, 4, 2, 128, 80, 64, 64, jnp.bfloat16),
+    ])
+    def test_gradients_match_ref(self, b, h, kh, d, window, bq, bk, dtype):
+        """dQ, dK and dV through the custom_vjp against jax.vjp of the
+        float32 oracle, at S 256."""
+        ks = jax.random.split(KEY, 4)
+        s = 256
+        q = jax.random.normal(ks[0], (b, s, h, d), dtype)
+        k = jax.random.normal(ks[1], (b, s, kh, d), dtype)
+        v = jax.random.normal(ks[2], (b, s, kh, d), dtype)
+        do = jax.random.normal(ks[3], (b, s, h, d), dtype)
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=True, window=window,
+                                   block_q=bq, block_k=bk, interpret=True)
+
+        def ref(q, k, v):
+            return attention_ref(q, k, v, causal=True, window=window)
+
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        got = jax.vjp(flash, q, k, v)[1](do)
+        want = jax.vjp(ref, *f32)[1](do.astype(jnp.float32))
+        tol = 2e-5 if dtype == jnp.float32 else 4e-2
+        for name, g, w in zip("qkv", got, want):
+            assert g.dtype == dtype, name
+            scale = float(jnp.max(jnp.abs(w)))
+            np.testing.assert_allclose(np.asarray(g, np.float32) / scale,
+                                       np.asarray(w) / scale, atol=tol,
+                                       err_msg=f"d{name}")
+
+    @pytest.mark.parametrize("causal,window,bq,bk", [
+        (True, None, 64, 64), (True, None, 128, 32), (True, None, 32, 128),
+        (True, 48, 32, 64), (True, 100, 64, 32), (False, None, 64, 32),
+    ])
+    def test_block_ranges_are_the_unmasked_blocks(self, causal, window,
+                                                  bq, bk):
+        """The blocks each kernel visits, and clamps its DMA to, are
+        exactly those with an unmasked entry; all others are skipped."""
+        s = 256
+        nq, nk = s // bq, s // bk
+        pos = np.arange(s)
+        keep = np.ones((s, s), bool)
+        if causal:
+            keep &= pos[None, :] <= pos[:, None]
+        if window is not None:
+            keep &= pos[None, :] > pos[:, None] - window
+        live = keep.reshape(nq, bq, nk, bk).any(axis=(1, 3))
+        for qi in range(nq):
+            lo, hi = fa_kernel._k_range(qi, bq, bk, nk, causal, window)
+            assert [lo <= ki <= hi for ki in range(nk)] == list(live[qi])
+        for ki in range(nk):
+            lo, hi = fa_kernel._q_range(ki, bq, bk, nq, causal, window)
+            assert [lo <= qi <= hi for qi in range(nq)] == list(live[:, ki])
+
+
+class TestAttentionDispatch:
+    """``models.layers.attention`` takes the flash kernel only where it
+    computes the same thing on a TPU over unpartitioned activations."""
+
+    CELL = dict(q_shape=(2, 2048, 16, 128), k_shape=(2, 2048, 16, 128))
+
+    @pytest.mark.parametrize("change,path", [
+        ({}, "flash"),
+        ({"platform": "cpu"}, "dense"),
+        ({"sharded": True}, "dense"),
+        ({"q_offset": 5}, "dense"),
+        ({"softcap": 30.0}, "dense"),
+        ({"q_shape": (2, 1, 16, 128)}, "dense"),               # decode
+        ({"q_shape": (2, 2000, 16, 128),
+          "k_shape": (2, 2000, 16, 128)}, "dense"),            # S % 128
+    ])
+    def test_path(self, change, path):
+        from repro.models.layers import attention_path
+        args = {**self.CELL, "platform": "tpu", **change}
+        q_shape, k_shape = args.pop("q_shape"), args.pop("k_shape")
+        assert attention_path(q_shape, k_shape, **args) == path
+
+    @pytest.mark.parametrize("platform,path", [("tpu", "flash"),
+                                               ("cpu", "dense")])
+    def test_counter_sees_the_model_path(self, monkeypatch, platform, path):
+        """A traced olmo forward counts one call per layer scan on the path
+        its platform gives, and none on the other."""
+        from repro.configs import get_config
+        from repro.models import layers, zoo
+        from repro.obs import attention_paths
+        monkeypatch.setattr(layers, "_platform", lambda: platform)
+        cfg = get_config("olmo-1b", smoke=True)
+        tok = jax.ShapeDtypeStruct((1, 128), jnp.int32)
+        before = attention_paths().snapshot()
+        jax.eval_shape(lambda p, t: zoo.loss_fn(cfg, p, {"tokens": t,
+                                                         "targets": t}),
+                       zoo.abstract(cfg), tok)
+        after = attention_paths().snapshot()
+        other = "dense" if path == "flash" else "flash"
+        assert after.get(path, 0) > before.get(path, 0)
+        assert after.get(other, 0) == before.get(other, 0)
+
+    def test_cross_attention_stays_dense(self, monkeypatch):
+        from repro.models import layers
+        from repro.obs import attention_paths
+        monkeypatch.setattr(layers, "_platform", lambda: "tpu")
+        x = jax.ShapeDtypeStruct((1, 128, 2, 64), jnp.bfloat16)
+        before = attention_paths().snapshot()
+        jax.eval_shape(layers.cross_attention, x, x, x)
+        after = attention_paths().snapshot()
+        assert after.get("dense", 0) == before.get("dense", 0) + 1
+        assert after.get("flash", 0) == before.get("flash", 0)
 
 
 class TestWkv6:

@@ -250,6 +250,21 @@ def test_compile_cache_placement(monkeypatch):
         assert (REPO_CACHE_DIR.parent / "pyproject.toml").exists()
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_hlo_source_file_canonicalization_regex", None)
+
+
+def test_compiled_source_files_are_named_from_the_checkout():
+    """After placing the cache, a lowered program names its source files
+    relative to the checkout: the Mosaic module of a Pallas kernel keeps
+    them, and it is part of the persistent cache's key."""
+    from repro.launch.compile_cache import REPO_ROOT, place_compile_cache
+    try:
+        place_compile_cache()
+        text = jax.jit(lambda x: x * 2).lower(1.0).as_text(debug_info=True)
+    finally:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex", None)
+    assert '"tests/test_substrate.py"' in text
+    assert str(REPO_ROOT) not in text
 
 
 class TestServing:

@@ -1,15 +1,19 @@
-"""Compile the three Pallas kernels for a described TPU v5e, no chip needed.
+"""Compile the three Pallas kernels, and a train step through the flash
+kernel, for a described TPU v5e, no chip needed.
 
 The TPU compiler is installed with jax; it compiles for a chip that is
 described and not attached, and refuses what the chip would refuse
 (unaligned tiles, too much VMEM) where interpret mode does not.  Shapes
-are the published widths ``chip_smoke.py`` runs on the chip.
+are the published widths ``chip_smoke.py`` runs on the chip.  Code that
+asks the default backend for its platform still sees the CPU here, so
+the train step's test steers ``models.layers`` onto the kernel path.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
 
+import dataclasses
 import os
 
 import jax
@@ -17,7 +21,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels.flash_attention.ops import flash_attention
+from repro.models import layers
+from repro.obs import attention_paths
 from repro.kernels.rglru.ops import rglru
 from repro.kernels.rwkv6.ops import wkv6
 
@@ -62,6 +69,44 @@ def test_flash_attention_compiles_for_v5e(one_chip, no_compile_cache):
     qkv = ((1, 2048, 16, 128), jnp.bfloat16)          # olmo-1b
     text = _compiled_text(flash_attention, one_chip, qkv, qkv, qkv)
     assert "tpu_custom_call" in text
+
+
+def test_flash_attention_grad_compiles_for_v5e(one_chip, no_compile_cache):
+    """Forward and both backward kernels through the custom_vjp, at the
+    olmo-1b-8l.pretrain-2k cell's shape."""
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    qkv = ((2, 2048, 16, 128), jnp.bfloat16)
+    text = _compiled_text(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+                          one_chip, qkv, qkv, qkv)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") >= 3
+
+
+@pytest.mark.parametrize("platform,path", [("tpu", "flash"),
+                                           ("cpu", "dense")])
+def test_olmo_layer_step_scores_stay_off_hbm(one_chip, no_compile_cache,
+                                             monkeypatch, platform, path):
+    """A one-layer olmo-1b train step at the cell's batch 2 x 2048 with
+    full remat: on the kernel path no f32[2,16,2048,2048] score buffer is
+    left in the compiled program; the jnp path has one."""
+    from repro.train import TrainConfig, abstract_state, make_train_step
+    monkeypatch.setattr(layers, "_platform", lambda: platform)
+    cfg = dataclasses.replace(get_config("olmo-1b"), n_layers=1)
+    tcfg = TrainConfig()
+
+    def place(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    state = jax.tree.map(place, abstract_state(cfg, tcfg))
+    tok = place(jax.ShapeDtypeStruct((2, 2048), jnp.int32))
+    before = attention_paths().snapshot().get(path, 0)
+    text = jax.jit(make_train_step(cfg, tcfg)).lower(
+        state, {"tokens": tok, "targets": tok}).compile().as_text()
+    assert attention_paths().snapshot().get(path, 0) > before
+    assert ("f32[2,16,2048,2048]" in text) == (path == "dense")
+    assert ("tpu_custom_call" in text) == (path == "flash")
 
 
 def test_rglru_compiles_for_v5e(one_chip, no_compile_cache):
