@@ -47,7 +47,7 @@ _SHAPE_RE = re.compile(r"(\w+)\[([0-9,]*)\](?:\{[^}]*\})?")
 
 # one HLO instruction: "%name = <result-type> <opcode>(...), attrs"
 _INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.+?)\s+"
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+?)\s+"
     r"(all-gather(?:-start)?|all-reduce(?:-start)?|reduce-scatter"
     r"|all-to-all|collective-permute(?:-start)?)\("
     r"(.*)$"
@@ -73,11 +73,16 @@ def shape_bytes(shape_str: str) -> int:
     return n * _DTYPE_BYTES[dtype]
 
 
-def _result_bytes(result_str: str) -> int:
-    """Largest shape inside a (possibly tuple) result type."""
+def _result_bytes(kind: str, result_str: str) -> int:
+    """Payload bytes of a collective's (possibly tuple) result type.  An
+    async ``-start`` of a gather or permute returns (operand, result,
+    context...): its largest element is the payload.  Otherwise a tuple is
+    a variadic collective, and every element is payload."""
     sizes = [shape_bytes(m.group(0))
              for m in _SHAPE_RE.finditer(result_str)]
-    return max(sizes, default=0)
+    if kind.endswith("-start") and not kind.startswith("all-reduce"):
+        return max(sizes, default=0)
+    return sum(sizes)
 
 
 def _parse_groups(attrs: str) -> list[list[int]]:
@@ -130,6 +135,8 @@ class CollectiveOp:
     group_size: int
     crosses_pod: bool
     link_bytes: float          # ring-model per-device bytes on the wire
+    name: str = ""             # the HLO instruction
+    channel: int | None = None  # channel_id, shared by an async op's parts
 
     @property
     def base_kind(self) -> str:
@@ -167,35 +174,47 @@ class CollectiveStats:
                 + " ".join(parts))
 
 
-def parse_collectives(hlo_text: str, pod_size: int | None = None
-                      ) -> CollectiveStats:
-    """Extract every collective op with its ring-model link bytes.
+_CHANNEL_RE = re.compile(r"channel_id=(\d+)")
+
+
+def parse_collective(line: str, pod_size: int | None = None
+                     ) -> CollectiveOp | None:
+    """The collective one HLO instruction line issues, or None (not a
+    collective, or the ``-done`` half of an async pair).
 
     ``pod_size``: number of devices per pod; a replica group containing
     members from different ``device // pod_size`` blocks is classified as
     pod-crossing (DCI)."""
-    ops: list[CollectiveOp] = []
-    for line in hlo_text.splitlines():
-        m = _INSTR_RE.match(line)
-        if not m:
-            continue
-        result_str, kind, attrs = m.group(1), m.group(2), m.group(3)
-        if kind.endswith("-done"):
-            continue
-        n_bytes = _result_bytes(result_str)
-        groups = _parse_groups(attrs)
-        g = len(groups[0]) if groups else 1
-        crosses = False
-        if pod_size and groups:
-            for grp in groups:
-                pods = {d // pod_size for d in grp}
-                if len(pods) > 1:
-                    crosses = True
-                    break
-        full, ring = _full_and_ring(kind, n_bytes, g)
-        ops.append(CollectiveOp(
-            kind=kind, full_bytes=full, group_size=g,
-            crosses_pod=crosses, link_bytes=ring))
+    m = _INSTR_RE.match(line)
+    if not m:
+        return None
+    name, result_str, kind, attrs = m.groups()
+    if kind.endswith("-done"):
+        return None
+    n_bytes = _result_bytes(kind, result_str)
+    groups = _parse_groups(attrs)
+    g = len(groups[0]) if groups else 1
+    crosses = False
+    if pod_size and groups:
+        for grp in groups:
+            pods = {d // pod_size for d in grp}
+            if len(pods) > 1:
+                crosses = True
+                break
+    full, ring = _full_and_ring(kind, n_bytes, g)
+    channel = _CHANNEL_RE.search(attrs)
+    return CollectiveOp(
+        kind=kind, full_bytes=full, group_size=g,
+        crosses_pod=crosses, link_bytes=ring, name=name,
+        channel=int(channel.group(1)) if channel else None)
+
+
+def parse_collectives(hlo_text: str, pod_size: int | None = None
+                      ) -> CollectiveStats:
+    """Extract every collective op with its ring-model link bytes (see
+    ``parse_collective``)."""
+    ops = [op for op in (parse_collective(line, pod_size)
+                         for line in hlo_text.splitlines()) if op]
     return CollectiveStats(ops=ops)
 
 

@@ -4,9 +4,11 @@ Runs real steps on the available devices (CPU smoke scale by default,
 TPU pods unchanged — the mesh adapts to jax.device_count()).  Wires every
 substrate piece: data pipeline + prefetch, sharded train step, async
 checkpointing, heartbeat and the recovery loop.
-``train(args)`` is the whole run; ``main`` parses the command line, calls
-it and prints its summary.  A run in which a step never succeeded raises,
-so the command exits non-zero.
+``train(args)`` is the whole run; at its end it counts the collectives
+of its compiled step into ``repro.obs.collectives()``, from a compile of
+their own.  ``main`` parses the command line, calls it and prints its
+summary.  A run in which a step never succeeded raises, so the command
+exits non-zero.
 """
 
 from __future__ import annotations
@@ -26,7 +28,7 @@ from repro.launch.compile_cache import place_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import zoo
 from repro.models.common import default_plan, replicated_plan
-from repro.obs import attention_paths
+from repro.obs import attention_paths, collectives, record_collectives
 from repro.optim import AdamWConfig
 from repro.sharding import named_sharding_tree
 from repro.train import (CheckpointManager, Heartbeat, TrainConfig,
@@ -115,6 +117,7 @@ def train(args) -> dict:
     heartbeat = Heartbeat(os.path.join(args.ckpt_dir, "heartbeat.json"))
     times: list[float] = []
     losses: dict[int, float] = {}
+    batch_shapes: dict = {}
     paths0 = attention_paths().snapshot()
 
     try:
@@ -136,6 +139,9 @@ def train(args) -> dict:
                 jax.block_until_ready(metrics["loss"])
                 dt = time.perf_counter() - t0
                 times.append(dt)
+                batch_shapes.update(
+                    (k, jax.ShapeDtypeStruct(v.shape, v.dtype))
+                    for k, v in jb.items())
                 heartbeat.beat(step)
                 return state, metrics
 
@@ -154,6 +160,8 @@ def train(args) -> dict:
                 data_prefetch=prefetch, on_metrics=on_metrics)
             manager.save(args.steps, state, extra={"final": True},
                          block=True)
+            record_collectives(
+                step_fn.lower(state, batch_shapes).compile().as_text())
     finally:
         prefetch.close()
     return {
@@ -167,6 +175,7 @@ def train(args) -> dict:
         "attention_paths": {
             path: int(n - paths0.get(path, 0))
             for path, n in attention_paths().snapshot().items()},
+        "collectives": collectives().snapshot(),
     }
 
 

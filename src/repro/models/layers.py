@@ -198,9 +198,10 @@ def attention_path(q_shape: tuple, k_shape: tuple, *, q_offset=0,
     """``"flash"`` where the Pallas kernel computes this self-attention,
     else ``"dense"`` (the jnp math, chunked over q for long sequences).
 
-    The kernel under ``shard_map`` for partitioned activations waits for a
-    sharded cell to measure it on (PERF.md §7); until then a mesh plan
-    keeps the jnp math, which GSPMD partitions."""
+    The kernel under ``shard_map`` for partitioned activations is to be
+    measured on the four-chip cell ``olmo-1b-16l.pretrain-2k-mesh2x2``
+    (PERF.md §7); until then a mesh plan keeps the jnp math, which GSPMD
+    partitions."""
     sq = q_shape[1]
     if (platform != "tpu" or sharded or softcap
             or not isinstance(q_offset, int) or q_offset

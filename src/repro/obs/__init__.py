@@ -3,12 +3,15 @@
 The telemetry subsystem every scheduling layer emits into — see
 ``repro.obs.trace`` for the ``TraceSink`` seam and the six decision-event
 families, ``repro.obs.metrics`` for the registry and the process's
-compile and attention-path counters, ``repro.obs.perfetto`` for
+compile and attention-path counters, ``repro.obs.collectives`` for the
+collectives of a compiled step, ``repro.obs.perfetto`` for
 Chrome-trace/Perfetto export, ``repro.obs.log`` for the shared ``repro``
 logger.  This package never imports the schedulers (they import us), so
 any later subsystem can emit into it without cycles.
 """
 
+from repro.obs.collectives import (collectives, count_collectives,
+                                   record_collectives)
 from repro.obs.log import configure_logging, get_logger
 from repro.obs.metrics import (CompileCounter, Counter, Gauge, Histogram,
                                MetricsRegistry, attention_paths,
@@ -30,6 +33,7 @@ __all__ = [
     "TraceEvent", "TraceSink",
     "CompileCounter", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "attention_paths", "compile_counter",
+    "collectives", "count_collectives", "record_collectives",
     "metrics_from_events", "pool_metrics", "slowdown_metrics",
     "cluster_trace", "export_cluster_trace",
     "export_pool_trace", "pool_trace", "write_trace",

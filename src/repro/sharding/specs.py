@@ -29,9 +29,14 @@ OP_CLASS_AXES: dict[str, tuple[str, ...]] = {
 
 
 def named_sharding_tree(plan: ShardingPlan, mesh: Mesh, logical_tree):
-    """Map a logical-axes spec tree to NamedShardings on ``mesh``."""
+    """Map a logical-axes spec tree to NamedShardings on ``mesh``.
+
+    A leaf on no mesh axis gets ``P()``, the form a jitted step returns it
+    in: ``jit`` keys its cache on the spec as written, so ``P(None, None)``
+    would compile the step again at its second call."""
     def leaf(spec: tuple) -> NamedSharding:
-        return NamedSharding(mesh, plan.spec_for(spec))
+        pspec = plan.spec_for(spec)
+        return NamedSharding(mesh, pspec if any(pspec) else P())
     return jax.tree.map(
         leaf, logical_tree,
         is_leaf=lambda x: isinstance(x, tuple)
