@@ -74,8 +74,14 @@ def build(args):
             else make_mesh((1,), ("data",))
         plan = replicated_plan()
         plan.batch_axes = ("data",) if n_dev > 1 else ()
-    cfg = dataclasses.replace(cfg, batch_axes=tuple(plan.batch_axes))
-    return cfg, tcfg, mesh, plan
+    return partitioned(cfg, plan), tcfg, mesh, plan
+
+
+def partitioned(cfg, plan):
+    """``cfg`` with ``plan``'s partition of the activations: the batch
+    axes, and the heads' axes the attention kernel is mapped over."""
+    return dataclasses.replace(cfg, batch_axes=tuple(plan.batch_axes),
+                               head_axes=tuple(plan.rules["heads"]))
 
 
 def init_placed_state(cfg, tcfg, mesh, plan, key) -> dict:

@@ -84,13 +84,11 @@ class ModelConfig:
     # the per-microbatch stacked scan carries (L,B,S,D) otherwise exceed
     # HBM (llama3-405b: 15.8 GiB/device of carries at 1 seq/device)
     seq_axes: tuple = ()
+    # mesh axes the attention heads (q and kv alike) are sharded over:
+    # the plan's "heads" rule, filled in with batch_axes by the launcher
+    head_axes: tuple = ()
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
-
-    @property
-    def sharded(self) -> bool:
-        """The activations are partitioned over a mesh."""
-        return bool(self.batch_axes or self.seq_axes)
 
     @property
     def hd(self) -> int:

@@ -7,11 +7,13 @@ Self-attention (``attention``) runs the Pallas flash kernel of
 ``repro.kernels.flash_attention`` (forward and backward, no (S, S) score
 tensor in HBM) when the program is traced for a TPU, the call is prefill
 or training (``q_offset`` 0, as many queries as keys), there is no logit
-softcap, the kernel's blocks tile S, and the activations are not
-partitioned over a mesh.  Every other call keeps the jnp math below:
-the CPU (tests, the dry-run), decode, cross-attention, softcapped models
-and sharded plans.  ``attention_path`` makes that choice, and
-``repro.obs.attention_paths()`` counts it at trace time.
+softcap and the kernel's blocks tile S.  On a mesh the kernel runs under
+``jax.shard_map``, one call per shard, where the batch divides by its
+shards and the q and kv heads by theirs.  Every other call keeps the jnp
+math below, which GSPMD partitions: the CPU (tests, the dry-run), decode,
+cross-attention, softcapped models and partitions that do not divide.
+``attention_path`` makes that choice, and ``repro.obs.attention_paths()``
+counts it at trace time.
 """
 
 from __future__ import annotations
@@ -21,6 +23,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.flash_attention.ops import block_size, flash_attention
 from repro.obs import attention_paths
@@ -193,19 +196,30 @@ def _platform() -> str:
 
 
 def attention_path(q_shape: tuple, k_shape: tuple, *, q_offset=0,
-                   softcap: float = 0.0, sharded: bool = False,
+                   softcap: float = 0.0, batch_axes: tuple = (),
+                   head_axes: tuple = (), mesh_shape=None,
                    platform: str) -> str:
     """``"flash"`` where the Pallas kernel computes this self-attention,
     else ``"dense"`` (the jnp math, chunked over q for long sequences).
 
-    The kernel under ``shard_map`` for partitioned activations is to be
-    measured on the four-chip cell ``olmo-1b-16l.pretrain-2k-mesh2x2``
-    (PERF.md §7); until then a mesh plan keeps the jnp math, which GSPMD
-    partitions."""
+    ``batch_axes``/``head_axes`` are the mesh axes the batch and the heads
+    are partitioned over, ``mesh_shape`` the size of each mesh axis.  The
+    kernel then runs once per shard, so the batch must divide by the
+    batch axes' size and both the q and the kv heads by the head axes':
+    split contiguously by one factor, each shard's q heads keep their kv
+    group."""
     sq = q_shape[1]
-    if (platform != "tpu" or sharded or softcap
+    if (platform != "tpu" or softcap
             or not isinstance(q_offset, int) or q_offset
             or sq != k_shape[1] or block_size(sq) is None):
+        return "dense"
+    sizes = mesh_shape or {}
+    if any(a not in sizes for a in (*batch_axes, *head_axes)):
+        return "dense"
+    batch_shards = math.prod(sizes[a] for a in batch_axes)
+    head_shards = math.prod(sizes[a] for a in head_axes)
+    if (q_shape[0] % batch_shards or q_shape[2] % head_shards
+            or k_shape[2] % head_shards):
         return "dense"
     return "flash"
 
@@ -213,25 +227,46 @@ def attention_path(q_shape: tuple, k_shape: tuple, *, q_offset=0,
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: int | None = None,
               q_offset: int = 0, softcap: float = 0.0,
-              unroll: bool = False, sharded: bool = False) -> jax.Array:
+              unroll: bool = False, batch_axes: tuple = (),
+              head_axes: tuple = ()) -> jax.Array:
     """Self-attention. q: (B,Sq,H,D), k/v: (B,Sk,K,D) with H % K == 0.
     Returns (B,Sq,H,D).
 
     ``q_offset``: absolute position of q[0] relative to k[0] (prefill=0,
     decode=Sk-1).  ``window``: keys further than ``window`` behind the
-    query are masked (sliding-window / local attention).  ``sharded``:
-    the activations are partitioned over a mesh (the config's
-    ``batch_axes`` or ``seq_axes``).  ``attention_path`` picks the flash
-    kernel or the jnp math."""
+    query are masked (sliding-window / local attention).
+    ``batch_axes``/``head_axes``: the mesh axes the batch and the heads
+    are partitioned over (the config's), on the mesh the trace runs
+    under.  ``attention_path`` picks the flash kernel or the jnp math."""
     path = attention_path(q.shape, k.shape, q_offset=q_offset,
-                          softcap=softcap, sharded=sharded,
+                          softcap=softcap, batch_axes=batch_axes,
+                          head_axes=head_axes,
+                          mesh_shape=jax.sharding.get_abstract_mesh().shape,
                           platform=_platform())
     attention_paths().counter(path).inc()
     if path == "flash":
         with jax.named_scope("flash"):
-            return flash_attention(q, k, v, causal=causal, window=window)
+            return _flash_per_shard(q, k, v, causal=causal, window=window,
+                                    batch_axes=batch_axes,
+                                    head_axes=head_axes)
     return _attention_jnp(q, k, v, causal=causal, window=window,
                           q_offset=q_offset, softcap=softcap, unroll=unroll)
+
+
+def _flash_per_shard(q, k, v, *, causal, window, batch_axes, head_axes):
+    """The flash kernel over whole sequences, once per (batch, heads)
+    shard of the context mesh; no collective runs inside the map, and
+    autodiff transposes the map, so the backward kernels run per shard
+    too.  Unpartitioned activations call the kernel directly."""
+    kernel = partial(flash_attention, causal=causal, window=window)
+    if not batch_axes and not head_axes:
+        return kernel(q, k, v)
+    spec = P(tuple(batch_axes) or None, None, tuple(head_axes) or None,
+             None)
+    # check_vma=False: the kernels' pallas_call outputs declare no
+    # varying mesh axes, which the map's check requires of them
+    return jax.shard_map(kernel, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _attention_jnp(q, k, v, *, causal, window, q_offset, softcap, unroll):

@@ -149,7 +149,8 @@ def _attn_block(cfg, lp, x, cos, sin):
     q = L.apply_rope(q, cos, sin)
     k = L.apply_rope(k, cos, sin)
     o = L.attention(q, k, v, causal=True, window=cfg.window,
-                    unroll=cfg.scan_unroll, sharded=cfg.sharded)
+                    unroll=cfg.scan_unroll, batch_axes=cfg.batch_axes,
+                    head_axes=cfg.head_axes)
     o = jnp.einsum("bsh,hd->bsd", o.reshape(b, s, cfg.n_heads * hd),
                    lp["wo"].astype(dt))
     x = x + o

@@ -94,7 +94,7 @@ def _mha(cfg, lp, xq, xkv=None, causal=False):
     v = _proj_heads(src, lp["wv"], b, sk, cfg.n_kv_heads, hd)
     if xkv is None:
         o = L.attention(q, k, v, causal=causal, unroll=cfg.scan_unroll,
-                        sharded=cfg.sharded)
+                        batch_axes=cfg.batch_axes, head_axes=cfg.head_axes)
     else:
         o = L.cross_attention(q, k, v, unroll=cfg.scan_unroll)
     return jnp.einsum("bsh,hd->bsd", o.reshape(b, sq, cfg.n_heads * hd),

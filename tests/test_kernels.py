@@ -1,6 +1,7 @@
 """Per-kernel shape/dtype sweeps vs. the pure-jnp oracles (interpret mode),
 and the models' choice between the flash kernel and the jnp attention."""
 
+import dataclasses
 import math
 
 import jax
@@ -161,14 +162,23 @@ class TestFlashAttention:
 
 class TestAttentionDispatch:
     """``models.layers.attention`` takes the flash kernel only where it
-    computes the same thing on a TPU over unpartitioned activations."""
+    computes the same thing on a TPU: over unpartitioned activations, or
+    once per shard where the mesh's batch and head shards divide them."""
 
     CELL = dict(q_shape=(2, 2048, 16, 128), k_shape=(2, 2048, 16, 128))
+    # olmo-1b-16l.pretrain-2k-mesh2x2: batch 16 over data, heads over model
+    MESH2X2 = dict(q_shape=(16, 2048, 16, 128), k_shape=(16, 2048, 16, 128),
+                   batch_axes=("data",), head_axes=("model",),
+                   mesh_shape={"data": 2, "model": 2})
 
     @pytest.mark.parametrize("change,path", [
         ({}, "flash"),
         ({"platform": "cpu"}, "dense"),
-        ({"sharded": True}, "dense"),
+        (MESH2X2, "flash"),
+        ({**MESH2X2, "k_shape": (16, 2048, 1, 128)}, "dense"),  # kv < model
+        ({**MESH2X2, "q_shape": (3, 2048, 16, 128),
+          "k_shape": (3, 2048, 16, 128)}, "dense"),            # B % data
+        ({**MESH2X2, "mesh_shape": {}}, "dense"),              # no mesh
         ({"q_offset": 5}, "dense"),
         ({"softcap": 30.0}, "dense"),
         ({"q_shape": (2, 1, 16, 128)}, "dense"),               # decode
@@ -200,6 +210,28 @@ class TestAttentionDispatch:
         other = "dense" if path == "flash" else "flash"
         assert after.get(path, 0) > before.get(path, 0)
         assert after.get(other, 0) == before.get(other, 0)
+
+    def test_counter_sees_the_sharded_kernel(self, monkeypatch):
+        """Traced under a (data 2, model 2) mesh with the batch and the
+        heads partitioned as ``default_plan`` does, an olmo forward counts
+        one kernel call per layer scan and no jnp attention."""
+        from repro.configs import get_config
+        from repro.models import layers, zoo
+        from repro.obs import attention_paths
+        monkeypatch.setattr(layers, "_platform", lambda: "tpu")
+        cfg = dataclasses.replace(get_config("olmo-1b", smoke=True),
+                                  batch_axes=("data",), head_axes=("model",))
+        mesh = jax.sharding.AbstractMesh(
+            (2, 2), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        tok = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+        before = attention_paths().snapshot()
+        with jax.sharding.use_abstract_mesh(mesh):
+            jax.eval_shape(lambda p, t: zoo.loss_fn(
+                cfg, p, {"tokens": t, "targets": t}), zoo.abstract(cfg), tok)
+        after = attention_paths().snapshot()
+        assert after.get("flash", 0) == before.get("flash", 0) + 1
+        assert after.get("dense", 0) == before.get("dense", 0)
 
     def test_cross_attention_stays_dense(self, monkeypatch):
         from repro.models import layers

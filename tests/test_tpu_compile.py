@@ -1,5 +1,6 @@
 """Compile the three Pallas kernels, and a train step through the flash
-kernel, for a described TPU v5e, no chip needed.
+kernel on one chip and on the four chips' (2,2) mesh, for a described
+TPU v5e, no chip needed.
 
 The TPU compiler is installed with jax; it compiles for a chip that is
 described and not attached, and refuses what the chip would refuse
@@ -18,8 +19,9 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.flash_attention.ops import flash_attention
@@ -107,6 +109,71 @@ def test_olmo_layer_step_scores_stay_off_hbm(one_chip, no_compile_cache,
     assert attention_paths().snapshot().get(path, 0) > before
     assert ("f32[2,16,2048,2048]" in text) == (path == "dense")
     assert ("tpu_custom_call" in text) == (path == "flash")
+
+
+@pytest.fixture(scope="module")
+def mesh_step_texts(topo):
+    """A one-layer olmo-1b train step at the four-chip cell's batch
+    16 x 2048, on the launcher's (data 2, model 2) mesh and
+    ``default_plan``, compiled for the described v5e:2x2 on the kernel
+    path (``"tpu"``) and on the jnp path (``"cpu"``): platform -> (HLO
+    text, attention paths counted while tracing it)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch.train import jit_train_step, partitioned
+    from repro.models.common import default_plan
+    from repro.sharding import batch_sharding, named_sharding_tree
+    from repro.train import TrainConfig, abstract_state, state_specs
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    plan = default_plan()
+    cfg = partitioned(dataclasses.replace(get_config("olmo-1b"), n_layers=1),
+                      plan)
+    tcfg = TrainConfig()
+    texts = {}
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp, jax.set_mesh(mesh):
+            state = jax.tree.map(
+                lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                   sharding=sh),
+                abstract_state(cfg, tcfg),
+                named_sharding_tree(plan, mesh, state_specs(cfg, tcfg)))
+            tok = jax.ShapeDtypeStruct((16, 2048), jnp.int32,
+                                       sharding=batch_sharding(plan, mesh))
+            for platform in ("tpu", "cpu"):
+                mp.setattr(layers, "_platform", lambda p=platform: p)
+                before = attention_paths().snapshot()
+                text = jit_train_step(cfg, tcfg, plan).lower(
+                    state, {"tokens": tok, "targets": tok}
+                ).compile().as_text()
+                after = attention_paths().snapshot()
+                texts[platform] = text, {
+                    p: after.get(p, 0) - before.get(p, 0)
+                    for p in ("flash", "dense")}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+    return texts
+
+
+@pytest.mark.parametrize("platform,path", [("tpu", "flash"),
+                                           ("cpu", "dense")])
+def test_olmo_mesh_step_maps_the_kernel_without_resharding(
+        mesh_step_texts, platform, path):
+    """On the kernel path the kernel runs per shard, each device's
+    f32[8,8,2048,2048] scores are gone, and the step runs the same
+    collectives, of the same sizes, as the jnp path: the map's boundary
+    reshards nothing."""
+    from repro.obs import count_collectives
+    text, paths = mesh_step_texts[platform]
+    other = "dense" if path == "flash" else "flash"
+    assert paths[path] > 0 and paths[other] == 0
+    assert ("tpu_custom_call" in text) == (path == "flash")
+    assert ("f32[8,8,2048,2048]" in text) == (path == "dense")
+    assert (count_collectives(text)
+            == count_collectives(mesh_step_texts["cpu"][0]))
 
 
 def test_rglru_compiles_for_v5e(one_chip, no_compile_cache):
