@@ -19,7 +19,8 @@ from repro.configs import ARCH_IDS, SHAPES, ShapeSpec, get_config, \
 from repro.hw import V5E, parse_collectives, dominant_term  # noqa: E402
 from repro.launch.mesh import make_production_mesh, pod_size  # noqa: E402
 from repro.models import zoo  # noqa: E402
-from repro.models.common import ModelConfig, ShardingPlan, default_plan  # noqa: E402
+from repro.models.common import (ModelConfig, ShardingPlan, default_plan,
+                                 partitioned)  # noqa: E402
 from repro.optim import AdamWConfig  # noqa: E402
 from repro.serving.kvcache import cache_shardings  # noqa: E402
 from repro.sharding import named_sharding_tree  # noqa: E402
@@ -28,38 +29,29 @@ from repro.train import (TrainConfig, abstract_state, make_serve_step,
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh(es); record memory analysis, cost analysis, and the
-collective-byte breakdown the roofline reads (EXPERIMENTS.md §Dry-run).
+collective-byte breakdown the roofline reads.
 
 The per-arch TrainConfigs below are the MEMORY-term decisions of the perf
-pass (microbatch count, remat policy, accumulator/moment dtypes) — see
-EXPERIMENTS.md §Perf for the iteration log that produced them.
+pass (microbatch count, remat, accumulator/moment dtypes).
 """
 
 TRAIN_CFGS: dict[str, TrainConfig] = {
     "llama3-405b": TrainConfig(
-        microbatches=16, remat=True, remat_policy="nothing",
-        accum_dtype="bfloat16",
+        microbatches=16, remat=True, accum_dtype="bfloat16",
         optimizer=AdamWConfig(moment_dtype="bfloat16")),
     "llama4-scout-17b-a16e": TrainConfig(
-        microbatches=8, remat=True, remat_policy="nothing",
-        accum_dtype="bfloat16",
+        microbatches=8, remat=True, accum_dtype="bfloat16",
         optimizer=AdamWConfig(moment_dtype="bfloat16")),
     "mixtral-8x7b": TrainConfig(
-        microbatches=8, remat=True, remat_policy="nothing",
+        microbatches=8, remat=True,
         optimizer=AdamWConfig(moment_dtype="bfloat16")),
-    "granite-3-8b": TrainConfig(microbatches=4, remat=True,
-                                remat_policy="nothing"),
-    "codeqwen1.5-7b": TrainConfig(microbatches=4, remat=True,
-                                  remat_policy="nothing"),
-    "llama-3.2-vision-11b": TrainConfig(microbatches=8, remat=True,
-                                        remat_policy="nothing"),
-    "olmo-1b": TrainConfig(microbatches=2, remat=True, remat_policy="dots"),
-    "rwkv6-1.6b": TrainConfig(microbatches=2, remat=True,
-                              remat_policy="nothing"),
-    "recurrentgemma-2b": TrainConfig(microbatches=2, remat=True,
-                                     remat_policy="nothing"),
-    "whisper-small": TrainConfig(microbatches=2, remat=True,
-                                 remat_policy="dots"),
+    "granite-3-8b": TrainConfig(microbatches=4, remat=True),
+    "codeqwen1.5-7b": TrainConfig(microbatches=4, remat=True),
+    "llama-3.2-vision-11b": TrainConfig(microbatches=8, remat=True),
+    "olmo-1b": TrainConfig(microbatches=2, remat=True),
+    "rwkv6-1.6b": TrainConfig(microbatches=2, remat=True),
+    "recurrentgemma-2b": TrainConfig(microbatches=2, remat=True),
+    "whisper-small": TrainConfig(microbatches=2, remat=True),
 }
 
 
@@ -98,7 +90,7 @@ def plan_for_shape(cfg: ModelConfig, shape: ShapeSpec,
     kv/sequence sharding group instead of idling."""
     plan = default_plan()
     # sequence parallelism for deep*wide models (train): layer-boundary
-    # carries otherwise exceed HBM (DESIGN.md §6; found via the 405b cell)
+    # carries otherwise exceed HBM (found via the 405b cell)
     if shape.kind == "train" and cfg.d_model >= 8192:
         plan.seq_axes = ("model",)
     # expert parallelism requires experts % axis == 0 (mixtral E=8 on a
@@ -147,8 +139,7 @@ def lower_cell(cfg: ModelConfig, shape: ShapeSpec, mesh, *,
                unroll: bool = False,
                micro_override: int | None = None,
                compile_only_text: bool = False) -> dict:
-    """Lower + compile one (arch x shape x mesh) cell; return the record
-    EXPERIMENTS.md consumes.
+    """Lower + compile one (arch x shape x mesh) cell; return its record.
 
     ``unroll=True`` fully unrolls layer/chunk scans so cost_analysis and
     the collective parse see every iteration (XLA counts while bodies
@@ -159,16 +150,13 @@ def lower_cell(cfg: ModelConfig, shape: ShapeSpec, mesh, *,
     tcfg = tcfg or train_config_for(cfg.arch_id)
     if micro_override is not None:
         tcfg = dataclasses.replace(tcfg, microbatches=micro_override)
-    cfg = dataclasses.replace(cfg, batch_axes=tuple(plan.batch_axes),
-                              seq_axes=tuple(plan.seq_axes),
-                              scan_unroll=unroll)
+    cfg = dataclasses.replace(partitioned(cfg, plan), scan_unroll=unroll)
     specs_in = input_specs(cfg, shape)
     n_dev = mesh.devices.size
 
     with jax.set_mesh(mesh):
         if shape.kind == "train":
-            step = make_train_step(cfg, tcfg,
-                                   batch_axes=tuple(plan.batch_axes))
+            step = make_train_step(cfg, tcfg)
             st_abs = abstract_state(cfg, tcfg)
             st_sh = named_sharding_tree(plan, mesh, state_specs(cfg, tcfg))
             b_sh = _batch_shardings(cfg, shape, plan, mesh, specs_in)
@@ -433,7 +421,7 @@ def measure_cell(cfg: ModelConfig, shape: ShapeSpec, mesh, *,
     rec["collectives"]["ici_link_bytes_step"] = fixed["ici_link_bytes"]
     rec["collectives"]["dci_link_bytes_step"] = fixed["dci_link_bytes"]
     compute_s = V5E.compute_time(fixed["flops_per_device"])
-    # two memory accountings (EXPERIMENTS.md §Roofline):
+    # two memory accountings:
     #  * memory_s_hlo — the spec formula HLO_bytes/(chips*bw).  The CPU
     #    backend's cost analysis counts every unfused elementwise
     #    operand, so this is a severe UPPER bound (5-10x on TPU, where
@@ -469,7 +457,7 @@ def measure_cell(cfg: ModelConfig, shape: ShapeSpec, mesh, *,
 
 def hbm_check(record: dict) -> tuple[float, bool]:
     # memory_analysis is PER-DEVICE (the SPMD module is the per-device
-    # program; verified empirically — see EXPERIMENTS.md §Dry-run notes)
+    # program; verified empirically)
     ma = record.get("memory_analysis", {})
     per_dev = (ma.get("argument_size_in_bytes", 0)
                + ma.get("temp_size_in_bytes", 0)

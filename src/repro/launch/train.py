@@ -27,7 +27,8 @@ from repro.data import DataConfig, Prefetcher, make_source
 from repro.launch.compile_cache import place_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import zoo
-from repro.models.common import default_plan, replicated_plan
+from repro.models.common import (default_plan, partitioned,
+                                 replicated_plan)
 from repro.obs import attention_paths, collectives, record_collectives
 from repro.optim import AdamWConfig
 from repro.sharding import named_sharding_tree
@@ -77,13 +78,6 @@ def build(args):
     return partitioned(cfg, plan), tcfg, mesh, plan
 
 
-def partitioned(cfg, plan):
-    """``cfg`` with ``plan``'s partition of the activations: the batch
-    axes, and the heads' axes the attention kernel is mapped over."""
-    return dataclasses.replace(cfg, batch_axes=tuple(plan.batch_axes),
-                               head_axes=tuple(plan.rules["heads"]))
-
-
 def init_placed_state(cfg, tcfg, mesh, plan, key) -> dict:
     """``init_state`` laid out on ``mesh`` per ``plan`` (call under
     ``jax.set_mesh(mesh)``)."""
@@ -95,11 +89,10 @@ def init_placed_state(cfg, tcfg, mesh, plan, key) -> dict:
 
 
 def jit_train_step(cfg, tcfg, plan):
-    """The jitted step.  The state is donated, so the new params and
-    optimizer moments reuse the old ones' device memory."""
-    return jax.jit(make_train_step(
-        cfg, tcfg, batch_axes=tuple(plan.batch_axes) or None),
-        donate_argnums=0)
+    """The jitted step over ``cfg``'s partition (``plan`` is not read).
+    The state is donated, so the new params and optimizer moments reuse
+    the old ones' device memory."""
+    return jax.jit(make_train_step(cfg, tcfg), donate_argnums=0)
 
 
 def data_source(args, cfg):

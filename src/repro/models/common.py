@@ -1,13 +1,14 @@
 """Model substrate: configs, parameter pytrees with logical sharding axes.
 
-Design (DESIGN.md §3):
+Design:
 
 * Models are pure functions over parameter pytrees (nested dicts of
   ``jnp.ndarray``).  No module framework — only jax.
 * Every parameter carries a *logical axis spec* (tuple of logical axis
   names, one per array dim) in a parallel pytree.  A ``ShardingPlan``
   maps logical names → mesh axes; this mapping is THE knob the paper-
-  technique autotuner turns (per-op-class shard degree, DESIGN.md A2).
+  technique autotuner turns (per-op-class shard degree).  ``partitioned``
+  copies the plan's partition of the activations onto a ``ModelConfig``.
 * ``abstract_params`` builds the same pytree out of ShapeDtypeStruct —
   the dry-run lowers against it without allocating a single byte.
 """
@@ -74,6 +75,8 @@ class ModelConfig:
     # cost analysis counts while-loop bodies ONCE, so rolled scans
     # undercount flops/bytes/collectives by the trip count
     scan_unroll: bool = False
+    # batch_axes, seq_axes and head_axes: the plan's partition of the
+    # activations, which ``partitioned`` copies from a ShardingPlan
     # mesh axes the activation batch dim is sharded over; when non-empty,
     # layer bodies emit with_sharding_constraint on their (B,S,D)
     # activations — remat/scan boundary tensors otherwise lose their
@@ -85,7 +88,7 @@ class ModelConfig:
     # HBM (llama3-405b: 15.8 GiB/device of carries at 1 seq/device)
     seq_axes: tuple = ()
     # mesh axes the attention heads (q and kv alike) are sharded over:
-    # the plan's "heads" rule, filled in with batch_axes by the launcher
+    # the plan's "heads" rule
     head_axes: tuple = ()
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
@@ -188,6 +191,15 @@ class ShardingPlan:
             self.spec_for, logical_tree,
             is_leaf=lambda x: isinstance(x, tuple)
             and all(isinstance(e, (str, type(None))) for e in x))
+
+
+def partitioned(cfg: ModelConfig, plan: ShardingPlan) -> ModelConfig:
+    """``cfg`` with ``plan``'s partition of the activations: the batch and
+    sequence axes, and the heads' axes the attention kernel is mapped
+    over."""
+    return dataclasses.replace(cfg, batch_axes=tuple(plan.batch_axes),
+                               seq_axes=tuple(plan.seq_axes),
+                               head_axes=tuple(plan.rules.get("heads", ())))
 
 
 def default_plan() -> ShardingPlan:

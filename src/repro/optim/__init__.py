@@ -1,10 +1,7 @@
 from repro.optim.adamw import (AdamWConfig, adamw_update, init_opt_state,
                                abstract_opt_state, schedule_lr, global_norm,
                                clip_by_global_norm)
-from repro.optim.compression import (CompressionConfig, compress,
-                                     init_error_state, wire_bytes)
 
 __all__ = ["AdamWConfig", "adamw_update", "init_opt_state",
            "abstract_opt_state", "schedule_lr", "global_norm",
-           "clip_by_global_norm", "CompressionConfig", "compress",
-           "init_error_state", "wire_bytes"]
+           "clip_by_global_norm"]

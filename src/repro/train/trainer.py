@@ -1,27 +1,21 @@
-"""Training loop substrate: train-step builder with gradient accumulation,
-remat policy, sharded state, and the paper-technique hooks.
-
-``make_train_step`` builds the jittable (state, batch) -> (state, metrics)
-function the launcher and the dry-run both lower:
+"""The train step: ``make_train_step`` builds the jittable
+(state, batch) -> (state, metrics) function the launcher and the dry-run
+both lower.
 
 * microbatch gradient accumulation via ``lax.scan`` (the microbatch count
-  is one of the autotuner's knobs — it trades activation memory against
-  per-step overhead, DESIGN.md A2);
-* activation checkpointing via ``jax.checkpoint`` with a configurable
-  policy around the per-microbatch loss (applies through the layer scan);
-* gradient compression with error feedback before the optimizer (the
-  cross-pod wire-byte saving is accounted in the roofline DCI term —
-  XLA's in-jit DP reduction itself stays dense; see optim/compression.py);
-* AdamW with schedule + global-norm clip.
+  trades activation memory against per-step overhead);
+* per-layer activation checkpointing: ``TrainConfig.remat`` wraps every
+  layer-scan body of the model in ``jax.checkpoint``;
+* AdamW with schedule and global-norm clip, under the ``optimizer``
+  named scope.
 
-TrainState is a plain dict {params, opt, error?} so checkpointing stays
+The training state is a plain dict {params, opt}, so checkpointing stays
 structural.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable
 
 import jax
@@ -29,8 +23,7 @@ import jax.numpy as jnp
 
 from repro.models import zoo
 from repro.models.common import ModelConfig
-from repro.optim import (AdamWConfig, CompressionConfig, adamw_update,
-                         compress, init_error_state, init_opt_state,
+from repro.optim import (AdamWConfig, adamw_update, init_opt_state,
                          abstract_opt_state)
 
 Params = Any
@@ -39,53 +32,45 @@ Params = Any
 @dataclasses.dataclass(frozen=True)
 class TrainConfig:
     microbatches: int = 1
+    # the one remat switch: make_train_step turns it into the model's
+    # per-layer ModelConfig.remat = "full"
     remat: bool = True
-    remat_policy: str = "dots"       # nothing | dots | everything
     accum_dtype: str = "float32"     # grad-accumulator dtype (bf16 halves
                                      # the accumulation buffer: needed to
                                      # fit llama3-405b on one pod)
     aux_weight: float = 0.01
     optimizer: AdamWConfig = dataclasses.field(default_factory=AdamWConfig)
-    compression: CompressionConfig = dataclasses.field(
-        default_factory=CompressionConfig)
 
 
 def init_state(cfg: ModelConfig, tcfg: TrainConfig, key) -> dict:
     params = zoo.init(cfg, key)
-    state = {"params": params,
-             "opt": init_opt_state(tcfg.optimizer, params)}
-    if tcfg.compression.scheme != "none" and tcfg.compression.ef:
-        state["error"] = init_error_state(params)
-    return state
+    return {"params": params,
+            "opt": init_opt_state(tcfg.optimizer, params)}
 
 
 def abstract_state(cfg: ModelConfig, tcfg: TrainConfig) -> dict:
     params = zoo.abstract(cfg)
-    state = {"params": params,
-             "opt": abstract_opt_state(tcfg.optimizer, params)}
-    if tcfg.compression.scheme != "none" and tcfg.compression.ef:
-        state["error"] = jax.tree.map(
-            lambda p: jax.ShapeDtypeStruct(p.shape, jnp.float32), params)
-    return state
+    return {"params": params,
+            "opt": abstract_opt_state(tcfg.optimizer, params)}
 
 
 def state_specs(cfg: ModelConfig, tcfg: TrainConfig) -> dict:
     """Logical-axes tree matching init_state's structure."""
     pspecs = zoo.specs(cfg)
-    out = {"params": pspecs,
-           "opt": {"mu": pspecs, "nu": pspecs, "step": ()}}
-    if tcfg.compression.scheme != "none" and tcfg.compression.ef:
-        out["error"] = pspecs
-    return out
+    return {"params": pspecs,
+            "opt": {"mu": pspecs, "nu": pspecs, "step": ()}}
 
 
 def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
                     batch_axes: tuple[str, ...] | None = None
                     ) -> Callable[[dict, dict], tuple[dict, dict]]:
-    """``batch_axes``: mesh axes the batch dim is sharded over; when set,
-    the microbatched tree gets an explicit sharding constraint — the
-    (B,) -> (n_micro, B/n) reshape is ambiguous to GSPMD and silently
-    de-shards the batch otherwise (found in the first dry-run)."""
+    """The microbatched tree gets an explicit sharding constraint over
+    the mesh axes the batch is sharded over, ``cfg.batch_axes`` (or
+    ``batch_axes`` where given): the (B,) -> (n_micro, B/n) reshape is
+    ambiguous to GSPMD and silently de-shards the batch otherwise (found
+    in the first dry-run)."""
+    if batch_axes is None:
+        batch_axes = cfg.batch_axes
     n_micro = tcfg.microbatches
     # per-LAYER remat (jax.checkpoint around the models' scan bodies):
     # checkpointing the whole loss would still stack full per-layer
@@ -136,19 +121,10 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
             lambda g: (g.astype(jnp.float32) / n_micro).astype(acc_dt), gsum)
         loss = lsum / n_micro
 
-        metrics = {"loss": loss}
-        if "error" in state:
-            grads, new_error, cm = compress(
-                tcfg.compression, grads, state["error"])
-            metrics.update(cm)
         with jax.named_scope("optimizer"):
             new_params, new_opt, om = adamw_update(
                 tcfg.optimizer, grads, state["opt"], params)
-        metrics.update(om)
-        new_state = {"params": new_params, "opt": new_opt}
-        if "error" in state:
-            new_state["error"] = new_error
-        return new_state, metrics
+        return {"params": new_params, "opt": new_opt}, {"loss": loss, **om}
 
     return train_step
 

@@ -131,7 +131,7 @@ def test_sharded_train_step_matches_single_device():
         import jax, jax.numpy as jnp, numpy as np, dataclasses
         from repro.configs import get_config
         from repro.launch.mesh import make_mesh
-        from repro.models.common import default_plan
+        from repro.models.common import default_plan, partitioned
         from repro.sharding import named_sharding_tree
         from repro.train import (TrainConfig, init_state, make_train_step,
                                  state_specs)
@@ -153,13 +153,12 @@ def test_sharded_train_step_matches_single_device():
         # sharded run
         mesh = make_mesh((2, 4), ("data", "model"))
         plan = default_plan()
-        cfg2 = dataclasses.replace(cfg, batch_axes=("data",))
+        cfg2 = partitioned(cfg, plan)
         with jax.set_mesh(mesh):
             st_sh = named_sharding_tree(plan, mesh, state_specs(cfg2, tcfg))
             state2 = init_state(cfg2, tcfg, key)
             state2 = jax.tree.map(jax.device_put, state2, st_sh)
-            step2 = jax.jit(make_train_step(cfg2, tcfg,
-                                            batch_axes=("data",)),
+            step2 = jax.jit(make_train_step(cfg2, tcfg),
                             in_shardings=(st_sh, None),
                             out_shardings=(st_sh, None))
             s2, m2 = step2(state2, batch)
@@ -168,6 +167,44 @@ def test_sharded_train_step_matches_single_device():
         print("OK", l_single, l_shard)
     """
     out = run_py(code, devices=8)
+    assert "OK" in out
+
+
+def test_train_step_takes_batch_axes_from_cfg():
+    """On the launcher's (2,2) mesh, the step of a config partitioned by
+    ``default_plan`` constrains its microbatches over the config's batch
+    axes: the same program as when they are passed by hand."""
+    code = """
+        import jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.configs import get_config
+        from repro.launch.mesh import make_mesh
+        from repro.models.common import default_plan, partitioned
+        from repro.sharding import named_sharding_tree
+        from repro.train import (TrainConfig, abstract_state,
+                                 make_train_step, state_specs)
+
+        plan = default_plan()
+        cfg = partitioned(get_config("olmo-1b", smoke=True), plan)
+        tcfg = TrainConfig(microbatches=2)
+        mesh = make_mesh((2, 2), ("data", "model"))
+        with jax.set_mesh(mesh):
+            state = jax.tree.map(
+                lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                   sharding=sh),
+                abstract_state(cfg, tcfg),
+                named_sharding_tree(plan, mesh, state_specs(cfg, tcfg)))
+            tok = jax.ShapeDtypeStruct(
+                (8, 32), jnp.int32, sharding=NamedSharding(mesh, P("data")))
+            texts = [jax.jit(step).lower(
+                state, {"tokens": tok, "targets": tok}).as_text()
+                for step in (make_train_step(cfg, tcfg),
+                             make_train_step(cfg, tcfg,
+                                             batch_axes=("data",)))]
+        assert texts[0] == texts[1]
+        print("OK")
+    """
+    out = run_py(code, devices=4)
     assert "OK" in out
 
 

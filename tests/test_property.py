@@ -16,7 +16,6 @@ from repro.hw.hlo import parse_collectives, shape_bytes
 from repro.multitenant import (JobQueue, PoolConfig, RuntimePool,
                                compare_timelines, corun_timeline,
                                pool_timeline, timeline_rows)
-from repro.optim import CompressionConfig, compress, init_error_state
 
 SETTINGS = dict(max_examples=25, deadline=None)
 
@@ -657,23 +656,6 @@ def test_pick_admissible_monotone_in_free_cores(threads, times, free,
     if pick is not None:
         assert wider is not None
         assert wider.threads <= pick.threads
-
-
-# ---------------------------------------------------------------------------
-# compression invariants
-# ---------------------------------------------------------------------------
-
-@settings(**SETTINGS)
-@given(seed=st.integers(0, 1000), ratio=st.floats(0.01, 0.9),
-       scheme=st.sampled_from(["topk", "int8"]))
-def test_error_feedback_conserves_signal(seed, ratio, scheme):
-    cfg = CompressionConfig(scheme=scheme, topk_ratio=ratio)
-    g = {"w": jax.random.normal(jax.random.PRNGKey(seed), (128,))}
-    err = init_error_state(g)
-    wire, new_err, _ = compress(cfg, g, err)
-    lhs = wire["w"].astype(jnp.float32) + new_err["w"]
-    rhs = g["w"].astype(jnp.float32) + err["w"]
-    np.testing.assert_allclose(np.asarray(lhs), np.asarray(rhs), atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,7 @@
-"""Substrate: optimizer, compression, data pipeline, checkpoint, fault
-tolerance, serving engine."""
+"""Substrate: optimizer, the train state and its partition, data
+pipeline, checkpoint, fault tolerance, serving engine."""
 
+import dataclasses
 import os
 import tempfile
 import time
@@ -10,13 +11,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_config
 from repro.data import DataConfig, MmapTokens, Prefetcher, SyntheticLM
-from repro.optim import (AdamWConfig, CompressionConfig, adamw_update,
-                         clip_by_global_norm, compress, init_error_state,
-                         init_opt_state, schedule_lr, wire_bytes)
+from repro.models.common import default_plan, partitioned, replicated_plan
+from repro.optim import (AdamWConfig, adamw_update, clip_by_global_norm,
+                         init_opt_state, schedule_lr)
 from repro.serving import Request, ServeEngine
 from repro.train import (CheckpointManager, Heartbeat, StragglerMonitor,
-                         run_with_recovery)
+                         TrainConfig, abstract_state, init_state,
+                         run_with_recovery, state_specs)
 
 
 class TestOptimizer:
@@ -54,30 +57,42 @@ class TestOptimizer:
         assert st["mu"]["w"].dtype == jnp.bfloat16
 
 
-class TestCompression:
-    @pytest.mark.parametrize("scheme", ["topk", "int8"])
-    def test_error_feedback_identity(self, scheme):
-        """wire + residual == grad + old_error (exact EF bookkeeping)."""
-        cfg = CompressionConfig(scheme=scheme, topk_ratio=0.25)
-        g = {"w": jax.random.normal(jax.random.PRNGKey(0), (64,))}
-        err = init_error_state(g)
-        wire, new_err, _ = compress(cfg, g, err)
-        lhs = wire["w"].astype(jnp.float32) + new_err["w"]
-        rhs = g["w"].astype(jnp.float32) + err["w"]
-        np.testing.assert_allclose(np.asarray(lhs), np.asarray(rhs),
-                                   atol=1e-5)
+# the three plans the code builds: one chip's, the launcher's (2,2) mesh,
+# and a pod-crossing train cell of the dry-run (plan_for_shape)
+PLANS = {
+    "replicated": replicated_plan(),
+    "default": default_plan(),
+    "pod_seq": dataclasses.replace(default_plan(), seq_axes=("model",),
+                                   batch_axes=("pod", "data")),
+}
 
-    def test_topk_sparsity(self):
-        cfg = CompressionConfig(scheme="topk", topk_ratio=0.1)
-        g = {"w": jax.random.normal(jax.random.PRNGKey(1), (1000,))}
-        wire, _, _ = compress(cfg, g, init_error_state(g))
-        nz = int(jnp.sum(wire["w"] != 0))
-        assert nz <= 110
 
-    def test_wire_bytes(self):
-        g = {"w": jnp.zeros((1000,), jnp.bfloat16)}
-        assert wire_bytes(CompressionConfig("int8"), g) == 1000.0
-        assert wire_bytes(CompressionConfig("none"), g) == 2000.0
+@pytest.mark.parametrize("name", list(PLANS))
+def test_partition_comes_from_the_plan(name):
+    plan = PLANS[name]
+    cfg = get_config("olmo-1b", smoke=True)
+    out = partitioned(cfg, plan)
+    assert out.batch_axes == tuple(plan.batch_axes)
+    assert out.seq_axes == tuple(plan.seq_axes)
+    assert out.head_axes == tuple(plan.rules["heads"])
+    rest = [f.name for f in dataclasses.fields(cfg)
+            if f.name not in ("batch_axes", "seq_axes", "head_axes")]
+    assert [getattr(out, k) for k in rest] == [getattr(cfg, k) for k in rest]
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "mixtral-8x7b"])
+def test_state_trees_agree(arch):
+    """The state, its abstract form and its logical specs are one tree,
+    with nothing beside the params and the optimizer's moments."""
+    cfg = get_config(arch, smoke=True)
+    tcfg = TrainConfig()
+    state = init_state(cfg, tcfg, jax.random.PRNGKey(0))
+    specs = state_specs(cfg, tcfg)
+    assert set(state) == set(specs) == {"params", "opt"}
+    tree = jax.tree.structure(state)
+    assert jax.tree.structure(abstract_state(cfg, tcfg)) == tree
+    assert jax.tree.structure(
+        specs, is_leaf=lambda x: isinstance(x, tuple)) == tree
 
 
 class TestData:

@@ -119,8 +119,8 @@ def mesh_step_texts(topo):
     path (``"tpu"``) and on the jnp path (``"cpu"``): platform -> (HLO
     text, attention paths counted while tracing it)."""
     from jax.experimental.compilation_cache import compilation_cache
-    from repro.launch.train import jit_train_step, partitioned
-    from repro.models.common import default_plan
+    from repro.launch.train import jit_train_step
+    from repro.models.common import default_plan, partitioned
     from repro.sharding import batch_sharding, named_sharding_tree
     from repro.train import TrainConfig, abstract_state, state_specs
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
