@@ -29,7 +29,8 @@ from repro.launch.mesh import make_mesh
 from repro.models import zoo
 from repro.models.common import (default_plan, partitioned,
                                  replicated_plan)
-from repro.obs import attention_paths, collectives, record_collectives
+from repro.obs import (attention_paths, collectives, record_collectives,
+                       tp_paths)
 from repro.optim import AdamWConfig
 from repro.sharding import named_sharding_tree
 from repro.train import (CheckpointManager, Heartbeat, TrainConfig,
@@ -118,6 +119,7 @@ def train(args) -> dict:
     losses: dict[int, float] = {}
     batch_shapes: dict = {}
     paths0 = attention_paths().snapshot()
+    tp0 = tp_paths().snapshot()
 
     try:
         with jax.set_mesh(mesh):
@@ -174,6 +176,8 @@ def train(args) -> dict:
         "attention_paths": {
             path: int(n - paths0.get(path, 0))
             for path, n in attention_paths().snapshot().items()},
+        "tp_paths": {path: int(n - tp0.get(path, 0))
+                     for path, n in tp_paths().snapshot().items()},
         "collectives": collectives().snapshot(),
     }
 

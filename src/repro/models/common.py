@@ -75,8 +75,8 @@ class ModelConfig:
     # cost analysis counts while-loop bodies ONCE, so rolled scans
     # undercount flops/bytes/collectives by the trip count
     scan_unroll: bool = False
-    # batch_axes, seq_axes and head_axes: the plan's partition of the
-    # activations, which ``partitioned`` copies from a ShardingPlan
+    # batch_axes, seq_axes, head_axes and ff_axes: the plan's partition
+    # of the activations, which ``partitioned`` copies from a ShardingPlan
     # mesh axes the activation batch dim is sharded over; when non-empty,
     # layer bodies emit with_sharding_constraint on their (B,S,D)
     # activations — remat/scan boundary tensors otherwise lose their
@@ -90,6 +90,8 @@ class ModelConfig:
     # mesh axes the attention heads (q and kv alike) are sharded over:
     # the plan's "heads" rule
     head_axes: tuple = ()
+    # mesh axes the MLP's hidden dim is sharded over: the plan's "ff" rule
+    ff_axes: tuple = ()
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
 
@@ -195,11 +197,13 @@ class ShardingPlan:
 
 def partitioned(cfg: ModelConfig, plan: ShardingPlan) -> ModelConfig:
     """``cfg`` with ``plan``'s partition of the activations: the batch and
-    sequence axes, and the heads' axes the attention kernel is mapped
-    over."""
+    sequence axes, the heads' axes the attention kernel is mapped over, and
+    the heads' and MLP's axes the blocks' tensor-parallel boundaries sit
+    on."""
     return dataclasses.replace(cfg, batch_axes=tuple(plan.batch_axes),
                                seq_axes=tuple(plan.seq_axes),
-                               head_axes=tuple(plan.rules.get("heads", ())))
+                               head_axes=tuple(plan.rules.get("heads", ())),
+                               ff_axes=tuple(plan.rules.get("ff", ())))
 
 
 def default_plan() -> ShardingPlan:

@@ -14,6 +14,13 @@ math below, which GSPMD partitions: the CPU (tests, the dry-run), decode,
 cross-attention, softcapped models and partitions that do not divide.
 ``attention_path`` makes that choice, and ``repro.obs.attention_paths()``
 counts it at trace time.
+
+A dense transformer block whose heads and MLP are split over mesh axes of
+more than one device runs its four tensor-parallel matmuls as the
+collective matmuls of ``repro.sharding.collective_matmul``, with the
+residual stream between blocks sharded over the sequence on those axes;
+every other block leaves the partition to GSPMD.  ``tp_boundary_path``
+makes that choice, and ``repro.obs.tp_paths()`` counts it at trace time.
 """
 
 from __future__ import annotations
@@ -27,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels.flash_attention.ops import block_size, flash_attention
 from repro.obs import attention_paths
+from repro.sharding.collective_matmul import ag_matmul_rs
 
 
 def maybe_remat(body, remat: str):
@@ -77,6 +85,34 @@ def seq_boundary(x: jax.Array, batch_axes: tuple, seq_axes: tuple
 
     ident.defvjp(fwd, bwd)
     return ident(x)
+
+
+def tp_boundary_path(cfg, seq_len: int, mesh_shape=None) -> str:
+    """``"collective"`` where a transformer block of ``cfg`` over
+    ``seq_len`` tokens runs its tensor-parallel boundaries as collective
+    matmuls, else ``"gspmd"`` (the einsums, GSPMD's all-reduces).
+
+    ``mesh_shape`` is the size of each mesh axis.  The collective path
+    needs a dense SwiGLU MLP, the heads and the MLP's hidden dim split
+    over the same axes of more than one device, none of them the batch's,
+    no sequence parallelism of the plan's own, and a sequence and head, kv
+    head and hidden counts that divide by those axes' size.  (The one plan
+    with sequence parallelism of its own, the dry-run's for models of
+    d_model 8192 and more, serves llama3-405b, whose 8 kv heads do not
+    divide over its 16-way model axis: its blocks keep ``seq_boundary``'s
+    handoffs.)"""
+    axes = tuple(cfg.head_axes)
+    sizes = mesh_shape or {}
+    if (not axes or cfg.moe_experts or cfg.act != "swiglu" or cfg.seq_axes
+            or tuple(cfg.ff_axes) != axes
+            or set(axes) & set(cfg.batch_axes)
+            or any(a not in sizes for a in axes)):
+        return "gspmd"
+    n = math.prod(sizes[a] for a in axes)
+    if (n == 1 or seq_len % n or cfg.n_heads % n or cfg.n_kv_heads % n
+            or cfg.d_ff % n):
+        return "gspmd"
+    return "collective"
 
 
 # ---------------------------------------------------------------------------
@@ -327,12 +363,20 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 # MLPs
 # ---------------------------------------------------------------------------
 
-def mlp_swiglu(p: dict, x: jax.Array) -> jax.Array:
+def _swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:
+    return jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
+
+
+def mlp_swiglu(p: dict, x: jax.Array, tp_axes: tuple = ()) -> jax.Array:
+    """``tp_axes``: the collective matmuls' axes (``tp_boundary_path``),
+    x then sequence-sharded over them, and so the output."""
     dt = x.dtype
+    if tp_axes:
+        return ag_matmul_rs(x, (p["w_gate"].astype(dt), p["w_up"].astype(dt)),
+                            p["w_down"].astype(dt), _swiglu, axes=tp_axes)
     gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(dt))
     up = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(dt))
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(dt) * up
-    return jnp.einsum("bsf,fd->bsd", act, p["w_down"].astype(dt))
+    return jnp.einsum("bsf,fd->bsd", _swiglu(gate, up), p["w_down"].astype(dt))
 
 
 def mlp_gelu(p: dict, x: jax.Array) -> jax.Array:

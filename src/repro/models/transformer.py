@@ -24,6 +24,8 @@ import jax.numpy as jnp
 
 from repro.models import layers as L
 from repro.models.common import ModelConfig, TreeBuilder
+from repro.obs import tp_paths
+from repro.sharding.collective_matmul import ag_matmul, matmul_rs
 
 
 # ---------------------------------------------------------------------------
@@ -91,18 +93,30 @@ def _norm(cfg: ModelConfig, x, scale):
 
 
 def _layer(cfg: ModelConfig, lp: dict, x: jax.Array,
-           cos: jax.Array, sin: jax.Array) -> tuple[jax.Array, tuple]:
+           cos: jax.Array, sin: jax.Array, tp: tuple = ()
+           ) -> tuple[jax.Array, tuple]:
     """One transformer block. x: (B,S,D). Returns (x', (k, v, aux)).
 
-    Sequence parallelism (cfg.seq_axes non-empty) follows the Megatron-SP
-    handoff: the residual stream / layer boundary is SEQ-SHARDED (so scan
-    carries stay small), each norm output is gathered into the
-    seq-unsharded tensor-parallel region, and each block output is
-    reduce-scattered back before the residual add.  Pinning only the
-    boundary (without explicit handoffs) makes the weight-grad
-    contractions conflict on the model axis and XLA materializes full
-    unsharded fp32 weight grads (found in the 405b dry-run)."""
-    x = L.seq_boundary(x, cfg.batch_axes, cfg.seq_axes)
+    ``tp``: the tensor-parallel axes where ``L.tp_boundary_path`` gives
+    the collective path.  The block is then Megatron sequence-parallel
+    over them: the residual stream, its norms and adds are sharded over
+    the sequence, and the q/k/v, out and MLP projections are collective
+    matmuls that gather the sequence into the tensor-parallel region and
+    scatter the sums back, their transfers under their own dots.
+
+    Sequence parallelism of the plan's own (cfg.seq_axes non-empty)
+    follows the Megatron-SP handoff under GSPMD: the residual stream /
+    layer boundary is SEQ-SHARDED (so scan carries stay small), each norm
+    output is gathered into the seq-unsharded tensor-parallel region, and
+    each block output is reduce-scattered back before the residual add.
+    Pinning only the boundary (without explicit handoffs) makes the
+    weight-grad contractions conflict on the model axis and XLA
+    materializes full unsharded fp32 weight grads (found in the 405b
+    dry-run)."""
+    if tp:
+        x = L.constrain_batch(x, cfg.batch_axes, tp)
+    else:
+        x = L.seq_boundary(x, cfg.batch_axes, cfg.seq_axes)
     dt = x.dtype
     b, s, d = x.shape
     hd = cfg.hd
@@ -110,19 +124,20 @@ def _layer(cfg: ModelConfig, lp: dict, x: jax.Array,
         h = _norm(cfg, x, lp["attn_norm"])
         if cfg.seq_axes:
             h = L.constrain_batch(h, cfg.batch_axes, ())   # gather into TP
-        q = jnp.einsum("bsd,dh->bsh", h, lp["wq"].astype(dt)
-                       ).reshape(b, s, cfg.n_heads, hd)
-        k = jnp.einsum("bsd,dh->bsh", h, lp["wk"].astype(dt)
-                       ).reshape(b, s, cfg.n_kv_heads, hd)
-        v = jnp.einsum("bsd,dh->bsh", h, lp["wv"].astype(dt)
-                       ).reshape(b, s, cfg.n_kv_heads, hd)
+        ws = (lp["wq"].astype(dt), lp["wk"].astype(dt), lp["wv"].astype(dt))
+        q, k, v = (ag_matmul(h, ws, axes=tp) if tp else
+                   [jnp.einsum("bsd,dh->bsh", h, w) for w in ws])
+        q = q.reshape(b, s, cfg.n_heads, hd)
+        k = k.reshape(b, s, cfg.n_kv_heads, hd)
+        v = v.reshape(b, s, cfg.n_kv_heads, hd)
         q = L.apply_rope(q, cos, sin)
         k = L.apply_rope(k, cos, sin)
         o = L.attention(q, k, v, causal=True, window=cfg.window,
                         unroll=cfg.scan_unroll, batch_axes=cfg.batch_axes,
                         head_axes=cfg.head_axes)
-        o = jnp.einsum("bsh,hd->bsd", o.reshape(b, s, cfg.n_heads * hd),
-                       lp["wo"].astype(dt))
+        o = o.reshape(b, s, cfg.n_heads * hd)
+        o = (matmul_rs(o, lp["wo"].astype(dt), axes=tp) if tp else
+             jnp.einsum("bsh,hd->bsd", o, lp["wo"].astype(dt)))
         if cfg.seq_axes:
             o = L.seq_boundary(o, cfg.batch_axes, cfg.seq_axes)  # RS back
         x = x + o
@@ -141,7 +156,7 @@ def _layer(cfg: ModelConfig, lp: dict, x: jax.Array,
                                          cfg.seq_axes)
             x = x + moe_out
         else:
-            m = (L.mlp_swiglu(lp, h2) if cfg.act == "swiglu"
+            m = (L.mlp_swiglu(lp, h2, tp) if cfg.act == "swiglu"
                  else L.mlp_gelu(lp, h2))
             if cfg.seq_axes:
                 m = L.seq_boundary(m, cfg.batch_axes, cfg.seq_axes)
@@ -158,13 +173,21 @@ def forward(cfg: ModelConfig, params: dict, batch: dict,
     tokens = batch["tokens"]
     b, s = tokens.shape
     dt = cfg.activation_dtype
+    path = L.tp_boundary_path(cfg, s, jax.sharding.get_abstract_mesh().shape)
+    tp_paths().counter(path).inc()
+    tp = cfg.head_axes if path == "collective" else ()
     with jax.named_scope("embed"):
         x = params["embed"]["table"].astype(dt)[tokens]
+        if tp:
+            # the lookup's partial sums over the vocabulary's shards meet
+            # here, scattered over the sequence as the first block takes
+            # them; left unpinned, GSPMD reshards the tokens instead
+            x = L.constrain_batch(x, cfg.batch_axes)
     pos = jnp.arange(s)
     cos, sin = L.rope_angles(pos, cfg.hd, cfg.rope_theta)
 
     def body(carry, lp):
-        y, (k, v, aux) = _layer(cfg, lp, carry, cos, sin)
+        y, (k, v, aux) = _layer(cfg, lp, carry, cos, sin, tp)
         ys = (jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1), aux) \
             if collect_cache else (aux,)
         return y, ys

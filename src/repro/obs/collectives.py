@@ -14,8 +14,11 @@ Per step means:
   counter with (a scan's ``i < n``), else 1;
 * an async collective counts once: its ``-done`` half is not counted, and
   the TPU compiler's async collective fusions (a start, continuations
-  that overlap the transfer with compute, a done, each holding a copy of
-  the op under one ``channel_id``) count as the one op they split;
+  that overlap the transfer with compute, a done, each a fused
+  computation holding a copy of the op under one ``channel_id``) count
+  as the one op they split; ops outside fused computations count one
+  each, whatever their ``channel_id`` (every ``collective-permute`` of a
+  ``ppermute`` carries channel 1);
 * the TPU compiler's all-reduce-scatter fusion (an ``all-reduce`` inside a
   computation named ``all-reduce-scatter...``) counts as the
   reduce-scatter it implements.
@@ -42,6 +45,7 @@ _CALLEE = re.compile(
 _NAME = re.compile(r"%([\w.\-]+)")
 _KNOWN_TRIPS = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
 _INT_CONSTANT = re.compile(r"=\s*s32\[\]\S*\s+constant\((\d+)\)")
+_FUSED_CALL = re.compile(r"\bcalls=%([\w.\-]+)")
 
 
 def _computations(text: str) -> tuple[str | None, dict[str, list[str]]]:
@@ -104,12 +108,19 @@ def _runs_per_step(entry: str, bodies: dict[str, list[str]]
     return runs
 
 
+def _fused(bodies: dict[str, list[str]]) -> set[str]:
+    """The computations a ``fusion`` instruction calls."""
+    return {callee for lines in bodies.values() for line in lines
+            if " fusion(" in line for callee in _FUSED_CALL.findall(line)}
+
+
 def count_collectives(hlo_text: str) -> dict[str, dict[str, float]]:
     """kind -> {"ops": ops per step, "bytes": bytes each device sends per
     step}, for the kinds the step issues, in ``KINDS`` order."""
     entry, bodies = _computations(hlo_text)
     if entry is None:
         return {}
+    fused = _fused(bodies)
     seen: dict[tuple, tuple[int, float]] = {}
     for name, n in _runs_per_step(entry, bodies).items():
         for line in bodies.get(name, ()):
@@ -120,7 +131,8 @@ def count_collectives(hlo_text: str) -> dict[str, dict[str, float]]:
             if kind == "all-reduce" and name.startswith("all-reduce-scatter"):
                 g = op.group_size
                 kind, sent = "reduce-scatter", op.full_bytes * (g - 1) / g
-            key = ((kind, op.channel) if op.channel is not None
+            key = ((kind, op.channel)
+                   if op.channel is not None and name in fused
                    else (kind, name, op.name))
             runs, _ = seen.get(key, (0, 0.0))
             seen[key] = (max(runs, n), sent)

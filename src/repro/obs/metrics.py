@@ -222,6 +222,17 @@ def attention_paths() -> MetricsRegistry:
     return _ATTENTION_PATHS
 
 
+_TP_PATHS = MetricsRegistry()
+
+
+def tp_paths() -> MetricsRegistry:
+    """The process-wide count of the path each transformer block's
+    tensor-parallel boundaries took: one counter per path
+    (``"collective"`` matmuls or ``"gspmd"``'s all-reduces), counted when
+    the block is traced."""
+    return _TP_PATHS
+
+
 # ---------------------------------------------------------------------------
 # PoolResult -> registry (the path RuntimePool.run always takes)
 # ---------------------------------------------------------------------------

@@ -105,6 +105,41 @@ def test_known_trip_count_wins_over_the_condition():
     assert count_collectives(text)["all-gather"]["ops"] == 3
 
 
+# two collective-permutes of a loop body, both under channel 1 as every
+# ``ppermute`` lowers, each an async start and done: two ops, not one
+PERMUTES = """\
+HloModule jit_step, is_scheduled=true
+
+%body (w: (s32[], bf16[8,64])) -> (s32[], bf16[8,64]) {
+  %w = (s32[], bf16[8,64]{1,0}) parameter(0)
+  %p = bf16[8,64]{1,0} get-tuple-element(%w), index=1
+  %cp-start = (bf16[8,64]{1,0}, bf16[8,64]{1,0}, u32[], u32[]) collective-permute-start(%p), channel_id=1, source_target_pairs={{0,1},{1,0}}
+  %cp-done = bf16[8,64]{1,0} collective-permute-done(%cp-start)
+  %cp-start.1 = (bf16[8,64]{1,0}, bf16[8,64]{1,0}, u32[], u32[]) collective-permute-start(%cp-done), channel_id=1, source_target_pairs={{0,1},{1,0}}
+  %cp-done.1 = bf16[8,64]{1,0} collective-permute-done(%cp-start.1)
+  ROOT %r = (s32[], bf16[8,64]{1,0}) tuple(%i, %cp-done.1)
+}
+
+%cond (w: (s32[], bf16[8,64])) -> pred[] {
+  %n = s32[] constant(4)
+  %w = (s32[], bf16[8,64]{1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%w), index=0
+  ROOT %lt = pred[] compare(%i, %n), direction=LT
+}
+
+ENTRY %main (x: bf16[8,64]) -> bf16[8,64] {
+  %x = bf16[8,64]{1,0} parameter(0)
+  %while.1 = (s32[], bf16[8,64]{1,0}) while(%t0), condition=%cond, body=%body
+  ROOT %y = bf16[8,64]{1,0} get-tuple-element(%while.1), index=1
+}
+"""
+
+
+def test_permutes_sharing_a_channel_count_apart():
+    got = count_collectives(PERMUTES)
+    assert got == {"collective-permute": {"ops": 8, "bytes": 8 * 8 * 64 * 2}}
+
+
 def test_registry_holds_the_last_step_recorded():
     record_collectives(TPU_LIKE)
     snap = collectives().snapshot()
@@ -124,7 +159,8 @@ def test_launcher_counts_collectives_of_the_mesh_step():
             res = train(parser().parse_args([
                 "--smoke", "--steps", "2", "--batch", "8", "--seq", "32",
                 "--save-every", "0", "--ckpt-dir", d]))
-        print("RESULT", json.dumps(res["collectives"]))
+        print("RESULT", json.dumps({**res["collectives"],
+                                    "tp_paths": res["tp_paths"]}))
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -138,6 +174,9 @@ def test_launcher_counts_collectives_of_the_mesh_step():
     assert got["all-gather.ops"] > 0 and got["all-gather.bytes"] > 0
     assert (got.get("all-reduce.bytes", 0) > 0
             or got.get("reduce-scatter.bytes", 0) > 0)
+    # its dense blocks run the collective matmuls, once traced
+    assert got["tp_paths"] == {"collective": 1}
+    assert got["collective-permute.ops"] > 0
 
 
 def test_launcher_counts_nothing_on_one_device():

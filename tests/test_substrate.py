@@ -1,6 +1,7 @@
 """Substrate: optimizer, the train state and its partition, data
 pipeline, checkpoint, fault tolerance, serving engine."""
 
+import contextlib
 import dataclasses
 import os
 import tempfile
@@ -75,9 +76,63 @@ def test_partition_comes_from_the_plan(name):
     assert out.batch_axes == tuple(plan.batch_axes)
     assert out.seq_axes == tuple(plan.seq_axes)
     assert out.head_axes == tuple(plan.rules["heads"])
+    assert out.ff_axes == tuple(plan.rules["ff"])
     rest = [f.name for f in dataclasses.fields(cfg)
-            if f.name not in ("batch_axes", "seq_axes", "head_axes")]
+            if f.name not in ("batch_axes", "seq_axes", "head_axes",
+                              "ff_axes")]
     assert [getattr(out, k) for k in rest] == [getattr(cfg, k) for k in rest]
+
+
+class TestTpBoundaryDispatch:
+    """``models.layers.tp_boundary_path`` runs a block's tensor-parallel
+    boundaries as collective matmuls only where the partition gives them
+    something to do: a dense block whose heads and MLP are split over the
+    same axes of more than one device, dividing the sequence and heads."""
+
+    # olmo-1b-16l.pretrain-2k-mesh2x2: default_plan on the (2, 2) mesh
+    MESH2X2 = {"data": 2, "model": 2}
+
+    @pytest.mark.parametrize("arch,plan,seq,mesh,path", [
+        ("olmo-1b", "default", 2048, MESH2X2, "collective"),
+        ("olmo-1b", "replicated", 2048, {"data": 1}, "gspmd"),  # one chip
+        ("olmo-1b", "default", 2048, {}, "gspmd"),              # no mesh
+        ("mixtral-8x7b", "default", 2048, MESH2X2, "gspmd"),    # MoE
+        ("olmo-1b", "pod_seq", 2048, {"pod": 2, **MESH2X2}, "gspmd"),
+        ("olmo-1b", "default", 2047, MESH2X2, "gspmd"),         # S % model
+        ("olmo-1b", "default", 2048, {"data": 4, "model": 1}, "gspmd"),
+        # 8 kv heads over a 16-way model axis: the dry-run's llama3-405b
+        ("llama3-405b", "default", 4096, {"data": 16, "model": 16},
+         "gspmd"),
+    ])
+    def test_path(self, arch, plan, seq, mesh, path):
+        from repro.models.layers import tp_boundary_path
+        cfg = partitioned(get_config(arch), PLANS[plan])
+        assert tp_boundary_path(cfg, seq, mesh) == path
+
+    def test_counter_sees_the_collective_path(self):
+        """Traced under the (data 2, model 2) mesh with ``default_plan``'s
+        partition, a dense olmo forward counts one collective step and no
+        GSPMD one; the same forward unpartitioned counts one GSPMD step."""
+        from repro.models import zoo
+        from repro.obs import tp_paths
+        mesh = jax.sharding.AbstractMesh(
+            (2, 2), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        tok = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+        smoke = get_config("olmo-1b", smoke=True)
+        for cfg, ctx, path in [
+                (partitioned(smoke, default_plan()),
+                 jax.sharding.use_abstract_mesh(mesh), "collective"),
+                (smoke, contextlib.nullcontext(), "gspmd")]:
+            before = tp_paths().snapshot()
+            with ctx:
+                jax.eval_shape(lambda p, t: zoo.loss_fn(
+                    cfg, p, {"tokens": t, "targets": t}),
+                    zoo.abstract(cfg), tok)
+            after = tp_paths().snapshot()
+            other = "gspmd" if path == "collective" else "collective"
+            assert after.get(path, 0) == before.get(path, 0) + 1
+            assert after.get(other, 0) == before.get(other, 0)
 
 
 @pytest.mark.parametrize("arch", ["olmo-1b", "mixtral-8x7b"])

@@ -16,6 +16,7 @@ this file.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -174,6 +175,90 @@ def test_olmo_mesh_step_maps_the_kernel_without_resharding(
     assert ("f32[8,8,2048,2048]" in text) == (path == "dense")
     assert (count_collectives(text)
             == count_collectives(mesh_step_texts["cpu"][0]))
+
+
+@pytest.fixture(scope="module")
+def mesh_step_gspmd_text(topo):
+    """``mesh_step_texts``' step on the kernel path with every block's
+    tensor-parallel boundaries left to GSPMD (``tp_boundary_path`` steered
+    to ``"gspmd"``): the program the collective matmuls replace."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch.train import jit_train_step
+    from repro.models.common import default_plan, partitioned
+    from repro.sharding import batch_sharding, named_sharding_tree
+    from repro.train import TrainConfig, abstract_state, state_specs
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    plan = default_plan()
+    cfg = partitioned(dataclasses.replace(get_config("olmo-1b"), n_layers=1),
+                      plan)
+    tcfg = TrainConfig()
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp, jax.set_mesh(mesh):
+            mp.setattr(layers, "_platform", lambda: "tpu")
+            mp.setattr(layers, "tp_boundary_path", lambda *a: "gspmd")
+            state = jax.tree.map(
+                lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                   sharding=sh),
+                abstract_state(cfg, tcfg),
+                named_sharding_tree(plan, mesh, state_specs(cfg, tcfg)))
+            tok = jax.ShapeDtypeStruct((16, 2048), jnp.int32,
+                                       sharding=batch_sharding(plan, mesh))
+            return jit_train_step(cfg, tcfg, plan).lower(
+                state, {"tokens": tok, "targets": tok}).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+
+
+def _block_all_reduces(text: str) -> list[str]:
+    """The all-reduces of (8, 2048, 2048) bf16 activations, one device's
+    share of the cell's (16, 2048, 2048), inside the block's scopes."""
+    return [line for line in text.splitlines()
+            if re.search(r"= bf16\[8,2048,2048\]\S* all-reduce(-start)?\(",
+                         line)
+            and re.search(r'op_name="[^"]*/(attention|mlp)/', line)]
+
+
+def _permutes_under_dots(text: str) -> list[int]:
+    """For each collective-permute-start, the matmul fusions the schedule
+    runs before its done."""
+    out, starts = [], {}
+    for line in text.splitlines():
+        m = re.match(r"\s*%(\S+) = .* collective-permute-start\(", line)
+        if m:
+            starts[m.group(1)] = 0
+            continue
+        m = re.match(r"\s*%\S+ = .* collective-permute-done\(%(\S+)\)",
+                     line)
+        if m and m.group(1) in starts:
+            out.append(starts.pop(m.group(1)))
+        elif "kind=kOutput" in line and "dot_general" in line:
+            starts = {k: n + 1 for k, n in starts.items()}
+    return out
+
+
+def test_olmo_mesh_step_hides_the_tp_sums_under_matmuls(
+        mesh_step_texts, mesh_step_gspmd_text):
+    """On the collective path the block sums its activations over the
+    ``model`` axis with no all-reduce: collective-permutes, each with a
+    matmul scheduled under it, carry them, and the step's collective bytes
+    stay within 15% of GSPMD's, whose five all-reduces are there."""
+    from repro.obs import count_collectives
+    text, _ = mesh_step_texts["tpu"]
+    assert _block_all_reduces(mesh_step_gspmd_text)
+    assert not _block_all_reduces(text)
+    under = _permutes_under_dots(text)
+    assert under and min(under) > 0, under
+
+    def total(t):
+        return sum(c["bytes"] for c in count_collectives(t).values())
+
+    assert total(text) <= 1.15 * total(mesh_step_gspmd_text), (
+        count_collectives(text), count_collectives(mesh_step_gspmd_text))
 
 
 def test_rglru_compiles_for_v5e(one_chip, no_compile_cache):
