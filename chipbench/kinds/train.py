@@ -8,8 +8,16 @@ first ``checked_steps`` steps through the same loop and feed as the
 window, reading what the check needs after the first and the last of
 them.  The window starts with the next step and closes at the first step
 that would start after ``seconds``; a traced run then traces
-``trace_steps`` more.  After the window the program's state is freed and
-the reference trains the checked steps from the same seed.
+``trace_steps`` more.  After the window the program's state is freed; a
+traced run then compiles the step once more for its HLO text, which names
+the traced operations' scopes; and the reference trains the checked steps
+from the same seed.
+
+The run's record, which the per-layer readers (``metrics/<name>.py``)
+read, holds the cell's ``config`` and ``traffic`` files as run, the
+window's counts and, in a traced run, ``trace``: ``trace_reduce``'s and
+``scope_reduce``'s fields, among them the self milliseconds per traced
+step of every kind of device operation and under every named scope.
 """
 
 from __future__ import annotations
@@ -27,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import check, data, reference, trace_reduce, work
+from chipbench import check, data, reference, scope_reduce, work
 from chipbench.reference.common import key_from_seed, leaf_norms, make_weights
 from chipbench.spec import ROOT, Cell, metric_reader
 
@@ -54,16 +62,16 @@ def program_args(cell: Cell) -> argparse.Namespace:
 
 
 def check_program_config(cell: Cell, cfg, tcfg) -> None:
-    """Every size and setting the files state is what the program runs."""
+    """Every size and setting the files state is what the program runs:
+    those every family has here, the family's own from its module."""
     c, t = cell.config, cell.traffic
     want = {
         "n_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
-        "n_heads": c["num_attention_heads"],
-        "n_kv_heads": c["num_key_value_heads"], "hd": c["head_dim"],
-        "d_ff": c["intermediate_size"], "vocab": c["vocab_size"],
+        "n_heads": c["num_attention_heads"], "vocab": c["vocab_size"],
         "padded_vocab": c["embedding_rows"], "dtype": c["compute_dtype"],
         "param_dtype": c["param_dtype"],
         "tie_embeddings": c.get("tie_word_embeddings", False),
+        **reference.family(c["family"]).program_settings(c),
     }
     got = {k: getattr(cfg, k) for k in want}
     opt = dataclasses.asdict(tcfg.optimizer)
@@ -74,6 +82,30 @@ def check_program_config(cell: Cell, cfg, tcfg) -> None:
     bad = {k: (want[k], got[k]) for k in want if want[k] != got[k]}
     if bad:
         raise ConfigMismatch(f"file vs program (want, got): {bad}")
+
+
+def step_hlo(cfg, tcfg, plan, mesh, state, batch) -> str:
+    """The train step's HLO text, compiled afresh for the shapes and
+    shardings of ``state`` and ``batch`` (abstract values) with the
+    persistent compile cache off: the cache keys a program without its op
+    names, so an executable loaded from it names its operations as
+    whichever source wrote the entry."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch import train as launch
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.set_mesh(mesh):
+            step = launch.jit_train_step(cfg, tcfg, plan)
+            return step.lower(state, batch).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+
+
+def _abstract(x):
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
 
 
 def _tree_shapes(tree) -> dict[str, tuple]:
@@ -241,6 +273,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                         "opt": init_opt_state(tcfg.optimizer, params)}
 
             state = jax.jit(make_state, out_shardings=shardings)(key)
+            state_shapes = jax.tree.map(_abstract, state)
             loop.step_fn = launch.jit_train_step(cfg, tcfg, plan)
             try:
                 run_with_recovery(loop, state, n_steps=1 << 62,
@@ -257,9 +290,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
 
     record = _window_record(cell, loop, c, t, devices)
     if trace and loop.traced:
+        batch_shapes = {k: jax.ShapeDtypeStruct(
+            v.shape, jax.dtypes.canonicalize_dtype(v.dtype))
+            for k, v in loop.fed[0].items()}
+        hlo = step_hlo(cfg, tcfg, plan, mesh, state_shapes, batch_shapes)
         files = sorted(loop.trace_dir.glob("**/*.xplane.pb"))
-        record["trace"] = (trace_reduce.reduce(trace_reduce.load(
-            str(files[-1]))) if files else None)
+        record["trace"] = (scope_reduce.trace_record(str(files[-1]), hlo)
+                           if files else None)
         shutil.rmtree(loop.trace_dir, ignore_errors=True)
 
     expected = [data.synthetic_lm(seed, s, c["vocab_size"], t["seq_len"],
@@ -295,8 +332,9 @@ def _window_record(cell: Cell, loop: Loop, c: dict, t: dict,
                    devices) -> dict:
     """What the window measured, for the end-to-end metrics and the
     per-layer readers."""
+    files = {"config": c, "traffic": t, "trace": None}
     if loop.window is None or loop.window_end is None:
-        return {"e2e": {}, "trace": None}
+        return {"e2e": {}, **files}
     first = loop.window[0]
     steps = [s for s in loop.step_s if first <= s < first + loop.timed_steps]
     window_s = loop.window_end - loop.window[1]
@@ -313,5 +351,5 @@ def _window_record(cell: Cell, loop: Loop, c: dict, t: dict,
         "flops_per_token": work.train_flops_per_token(c, t["seq_len"]),
         "device_kind": devices[0].device_kind,
         "chips": len(devices),
-        "trace": None,
+        **files,
     }

@@ -1,0 +1,9 @@
+"""Device self milliseconds per traced step under the program's
+``attention`` scope (norm, q/k/v, RoPE, attention, out projection,
+residual), averaged over the devices; 0 where the traced step names other
+scopes but not this one."""
+
+
+def read(record: dict):
+    scopes = (record.get("trace") or {}).get("scope_ms_per_step")
+    return scopes.get("attention", 0.0) if scopes else None

@@ -2,6 +2,15 @@
 that the check compares with: the first steps of AdamW from the seed's
 weights on the traffic's first batches.
 
+A family is the module ``reference/<family>.py``, named by a
+configuration file's ``family``.  It owns everything the harness knows of
+that architecture: ``layout(c)``, the parameter tree; ``loss(c, params,
+tokens, targets, control)``; ``forward_macs(c, seq)``, the forward pass's
+multiply-adds per token; ``program_settings(c)``, the program's model
+settings that the file fixes beyond those every family has; and
+``TINY``, its configuration at the CPU tests' size.  Adding a family is
+adding its module.
+
 Nothing here imports the program.  ``run`` places the reference on every
 device it is given, each parameter split along its largest axis that the
 device count divides, so that a model that needs several chips to train
@@ -11,6 +20,7 @@ fits them.
 from __future__ import annotations
 
 import importlib
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +31,12 @@ from chipbench.reference.common import adamw, leaf_norms, make_weights
 
 def family(name: str):
     return importlib.import_module(f"chipbench.reference.{name}")
+
+
+def families() -> list[str]:
+    """The names of every family module here."""
+    return sorted(p.stem for p in Path(__file__).parent.glob("*.py")
+                  if p.stem not in ("__init__", "common"))
 
 
 def _split_largest(shape, n: int, mesh):

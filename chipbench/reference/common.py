@@ -72,7 +72,7 @@ def make_weights(layout: dict, key) -> dict:
     """Weights for ``layout`` (path -> (shape, init)), each leaf drawn
     from its own fold of ``key``.  An init is ``("normal", std)``,
     ``("uniform", lo, hi)``, ``("const", value)``, ``("one_plus_normal",
-    std)`` or ``("decay_speed",)`` (RWKV6's per-layer ramp of w0)."""
+    std)`` or ``(fn,)``, a family's own deterministic ``fn(shape)``."""
     out: dict = {}
     for i, (path, (shape, init)) in enumerate(sorted(layout.items())):
         k = jax.random.fold_in(key, i)
@@ -85,8 +85,8 @@ def make_weights(layout: dict, key) -> dict:
             arr = jax.random.uniform(k, shape, F32, init[1], init[2])
         elif kind == "const":
             arr = jnp.full(shape, init[1], F32)
-        elif kind == "decay_speed":
-            arr = _decay_speed(shape)
+        elif callable(kind):
+            arr = kind(shape)
         else:
             raise ValueError(f"unknown init {init!r} for {path}")
         node = out
@@ -95,15 +95,6 @@ def make_weights(layout: dict, key) -> dict:
             node = node.setdefault(p, {})
         node[parts[-1]] = arr
     return out
-
-
-def _decay_speed(shape):
-    """RWKV6's w0 at initialisation: for layer l of L and channel n of d,
-    -6 + 5 (n/(d-1))^(0.7 + 1.3 l/(L-1))."""
-    n_layers, d = shape
-    n = jnp.arange(d, dtype=F32) / max(d - 1, 1)
-    ratio = jnp.arange(n_layers, dtype=F32) / max(n_layers - 1, 1)
-    return -6.0 + 5.0 * n[None, :] ** (0.7 + 1.3 * ratio[:, None])
 
 
 def fan_in_std(shape) -> float:

@@ -28,6 +28,34 @@ import jax.numpy as jnp
 
 from chipbench.reference.common import F32, cross_entropy, fan_in_std, mm
 
+# the CPU tests' size of the family: two layers at a 32nd of the width
+TINY = {
+    "name": "olmo-tiny", "arch": "olmo-1b", "smoke": True,
+    "family": "dense", "num_hidden_layers": 2, "hidden_size": 64,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 16,
+    "intermediate_size": 256, "vocab_size": 512, "embedding_rows": 512,
+    "rope_theta": 10000.0, "layer_norm_eps": 1e-5,
+    "tie_word_embeddings": True, "compute_dtype": "float32",
+    "param_dtype": "float32"}
+
+
+def program_settings(c: dict) -> dict:
+    """The program's model settings (``ModelConfig`` attribute -> value)
+    that the configuration file fixes, besides those every family has."""
+    return {"n_kv_heads": c["num_key_value_heads"], "hd": c["head_dim"],
+            "d_ff": c["intermediate_size"]}
+
+
+def forward_macs(c: dict, seq: int) -> float:
+    """Multiply-adds of the forward pass per token (``work.py``'s rules):
+    the q, k, v, o and SwiGLU projections, causal attention over t + 1
+    keys at position t, and the head."""
+    d, hd = c["hidden_size"], c["head_dim"]
+    hq, hkv = c["num_attention_heads"] * hd, c["num_key_value_heads"] * hd
+    proj = d * hq * 2 + d * hkv * 2 + 3 * d * c["intermediate_size"]
+    attn = 2 * hq * (seq + 1) / 2          # q.k and p.v over (t+1) keys
+    return c["num_hidden_layers"] * (proj + attn) + d * c["vocab_size"]
+
 
 def layout(c: dict) -> dict:
     """The parameter tree: path -> (shape, init)."""

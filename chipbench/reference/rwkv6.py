@@ -38,6 +38,34 @@ from chipbench.reference.common import (F32, cross_entropy, fan_in_std, mm,
 LORA = 32          # rank of the ddlerp and decay low-rank maps
 CHUNK = 64         # tokens per rematerialised block of the recurrence
 
+# the CPU tests' size of the family: two layers at a 32nd of the width
+TINY = {
+    "name": "rwkv6-tiny", "arch": "rwkv6-1.6b", "smoke": True,
+    "family": "rwkv6", "num_hidden_layers": 2, "hidden_size": 64,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 16,
+    "intermediate_size": 128, "vocab_size": 512, "embedding_rows": 512,
+    "tie_word_embeddings": False, "compute_dtype": "float32",
+    "param_dtype": "float32"}
+
+
+def program_settings(c: dict) -> dict:
+    """The program's model settings (``ModelConfig`` attribute -> value)
+    that the configuration file fixes, besides those every family has."""
+    return {"n_kv_heads": c["num_key_value_heads"], "hd": c["head_dim"],
+            "d_ff": c["intermediate_size"]}
+
+
+def forward_macs(c: dict, seq: int) -> float:
+    """Multiply-adds of the forward pass per token (``work.py``'s rules):
+    time mix with its low-rank maps, the WKV state update and read,
+    channel mix, and the head."""
+    d, ff, lora = c["hidden_size"], c["intermediate_size"], LORA
+    time_mix = 5 * d * d + 2 * (5 * lora) * d + 2 * lora * d
+    wkv = 2 * d * (d // c["num_attention_heads"])   # state update + read
+    channel_mix = 2 * d * ff + d * d
+    return (c["num_hidden_layers"] * (time_mix + wkv + channel_mix)
+            + d * c["vocab_size"])
+
 
 def layout(c: dict) -> dict:
     n, d = c["num_hidden_layers"], c["hidden_size"]
@@ -52,7 +80,7 @@ def layout(c: dict) -> dict:
         "layers/cm_norm": ((n, d), ("normal", 0.1)),
         "layers/tm_mu": ((n, 5, d), ("uniform", 0.0, 1.0)),
         "layers/tm_lora_b": ((n, 5, LORA, d), ("normal", 0.1)),
-        "layers/w0": ((n, d), ("decay_speed",)),
+        "layers/w0": ((n, d), (decay_speed,)),
         "layers/w_lora_b": ((n, LORA, d), ("normal", 0.1)),
         "layers/u": ((n, nh, hd), ("uniform", 0.0, 1.0)),
         "layers/ln_x": ((n, d), ("one_plus_normal", 0.1)),
@@ -66,6 +94,15 @@ def layout(c: dict) -> dict:
                         ("cm_wv", (n, ff, d)), ("cm_wr", (n, d, d))):
         out[f"layers/{name}"] = (shape, ("normal", fan_in_std(shape)))
     return out
+
+
+def decay_speed(shape):
+    """w0 at initialisation: for layer l of L and channel n of d,
+    -6 + 5 (n/(d-1))^(0.7 + 1.3 l/(L-1))."""
+    n_layers, d = shape
+    n = jnp.arange(d, dtype=F32) / max(d - 1, 1)
+    ratio = jnp.arange(n_layers, dtype=F32) / max(n_layers - 1, 1)
+    return -6.0 + 5.0 * n[None, :] ** (0.7 + 1.3 * ratio[:, None])
 
 
 def rms_norm(x, scale):

@@ -1,23 +1,27 @@
 """From a profiler trace of the program's train step and the step's
-compiled HLO text to device time per named scope of the program, and the
-device idle time under the program's data fetch.
+compiled HLO text to device time per step under each named scope of the
+program, and the device idle time under the program's data fetch.
 
 The window is ``trace_reduce``'s: the stretch from the first to the last
 host step span (``train``).  Each device operation's self time inside it
-goes to the innermost of the program's named scopes (``SCOPES``) in its
-``op_name``, or to ``unscoped``.  Operations are named by their HLO
-instruction, so the ``op_name``s come from the compiled step's HLO text:
-a fusion takes the ``op_name`` of its ``dot`` or ``convolution`` if it
-holds one, else that of its root, so a weight-gradient matmul fused into
-a loop's ``dynamic-update-slice`` is charged to its layer.  The
-program's own host spans, one ``repro.step`` per iteration of its loop
-holding one ``repro.data`` around its ``batch_at``, give the device idle
-time under the data fetch.
+counts under every named scope in its ``op_name``: every component of
+the path but the last (the primitive), with transform wrappers stripped
+(``transpose(jvp(attention))`` is ``attention``, ``jit(train_step)`` is
+``train_step``).  Nested scopes each count it, so ``flash`` inside
+``attention`` counts in both; scopes that do not nest split the time.
+Operations are named by their HLO instruction, so the ``op_name``s come
+from the compiled step's HLO text: a fusion takes the ``op_name`` of its
+``dot`` or ``convolution`` if it holds one, else that of its root, so a
+weight-gradient matmul fused into a loop's ``dynamic-update-slice`` is
+charged to its layer.  The program's own host spans, one ``repro.step``
+per iteration of its loop holding one ``repro.data`` around its
+``batch_at``, give the device idle time under the data fetch.
 
 The HLO text has to come from a compile that did not load the step from
 the persistent compile cache: the cache keys a program without its op
 names, so a loaded executable names its operations as whichever source
-of the same program wrote the entry.
+of the same program wrote the entry.  The trace's events carry no
+``op_name`` of their own that ``ProfileData`` reads.
 
 Importing this module touches no accelerator.
 """
@@ -33,15 +37,13 @@ from chipbench.trace_reduce import STEP_SPAN, Trace
 
 PROGRAM_STEP = "repro.step"
 PROGRAM_DATA = "repro.data"
-SCOPES = ("embed", "attention", "mlp", "head", "optimizer")
-UNSCOPED = "unscoped"
 
 _INSTR = re.compile(r"^\s*(ROOT\s+)?%([\w.\-]+) = (.*)$")
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+) .*\{\s*$")
 _OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
 _CALLS = re.compile(r"calls=%([\w.\-]+)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
-_SCOPE = re.compile(r"(?:\w+\()*(\w+)\)*")      # jvp(attention) -> attention
+_SCOPE = re.compile(r"(?:\w+\()*([^()]*)\)*")   # jvp(attention) -> attention
 
 
 def load(path: str) -> Trace:
@@ -114,24 +116,12 @@ def hlo_op_names(text: str) -> dict[str, str]:
     return {name: charged(ins) for name, ins in named.items()}
 
 
-def scope_of(op_name: str) -> str:
-    """The innermost of ``SCOPES`` named in ``op_name``, or ``unscoped``."""
-    found = UNSCOPED
-    for part in op_name.split("/"):
-        m = _SCOPE.fullmatch(part)
-        if m and m.group(1) in SCOPES:
-            found = m.group(1)
-    return found
-
-
-def attribute(per_op: dict[str, float], names: dict[str, str]
-              ) -> dict[str, float]:
-    """Seconds per scope (and ``unscoped``) of operations' self times;
-    an operation the HLO text does not name is unscoped."""
-    out = dict.fromkeys(SCOPES + (UNSCOPED,), 0.0)
-    for n, t in per_op.items():
-        out[scope_of(names.get(n, ""))] += t
-    return out
+def scope_names(op_name: str) -> set[str]:
+    """Every named scope in ``op_name``: each component of the path but
+    the last, with transform wrappers stripped; a component that strips
+    to nothing (``transpose(jvp())``) names none."""
+    return {m.group(1) for part in op_name.split("/")[:-1]
+            if (m := _SCOPE.fullmatch(part)) and m.group(1)}
 
 
 def _overlap(a: tuple[float, float], b: tuple[float, float]) -> float:
@@ -139,14 +129,15 @@ def _overlap(a: tuple[float, float], b: tuple[float, float]) -> float:
 
 
 def reduce(trace: Trace, hlo: str) -> dict | None:
-    """Averaged over devices: ``self_s``, all operations' self time in the
-    window; ``scope_s``, that time split over ``SCOPES`` and ``unscoped``
-    by the HLO text ``hlo`` (None where no operation carries a scope);
-    ``data_idle_s``, idle time under the program's ``repro.data`` spans
-    (None where the trace has none).  Also ``steps``, the window's step
-    spans, and ``program_steps``, the ``repro.step`` spans that start
-    inside the window (each holds one ``repro.data``).  None without a
-    step span or a device operation."""
+    """Averaged over devices, per step of the window: ``self_ms_per_step``,
+    all operations' self time; ``scope_ms_per_step``, the self time under
+    each scope the HLO text ``hlo`` names (empty where it names none; an
+    operation it does not name counts in the total alone).  Also
+    ``data_idle_s``, idle seconds under the program's ``repro.data`` spans
+    (None where the trace has none), ``steps``, the window's step spans,
+    and ``program_steps``, the ``repro.step`` spans that start inside the
+    window (each holds one ``repro.data``).  None without a step span or
+    a device operation."""
     steps = [(s, e) for n, s, e in trace.host_spans if n == STEP_SPAN]
     devices = {d: v for d, v in trace.device_ops.items() if v}
     if not steps or not devices:
@@ -163,37 +154,26 @@ def reduce(trace: Trace, hlo: str) -> dict | None:
         busy = trace_reduce.union([(s, e) for _, s, e in inside])
         idle = trace_reduce.gaps(busy, lo, hi)
         data_idle_s += sum(_overlap(g, f) for g in idle for f in fetches)
-    n_dev = len(devices)
-    split = attribute(per_op, hlo_op_names(hlo))
+    names = hlo_op_names(hlo)
+    scoped: collections.Counter = collections.Counter()
+    for n, t in per_op.items():
+        for scope in scope_names(names.get(n, "")):
+            scoped[scope] += t
+    ms = 1e3 / (len(devices) * len(steps))
     return {
         "steps": len(steps),
-        "self_s": sum(per_op.values()) / n_dev,
-        "scope_s": ({k: t / n_dev for k, t in split.items()}
-                    if any(split[s] for s in SCOPES) else None),
-        "data_idle_s": data_idle_s / n_dev if fetches else None,
+        "self_ms_per_step": sum(per_op.values()) * ms,
+        "scope_ms_per_step": {k: t * ms for k, t in scoped.items()},
+        "data_idle_s": (data_idle_s / len(devices) if fetches else None),
         "program_steps": sum(1 for n, s, _ in trace.host_spans
                              if n == PROGRAM_STEP and lo <= s < hi),
     }
 
 
-# per-step readings of a reduction: name -> the scopes it sums
-READINGS = {"attention": ("attention",), "mlp": ("mlp",),
-            "vocab": ("embed", "head"), "optimizer": ("optimizer",),
-            "unscoped": (UNSCOPED,)}
-
-
-def per_step_ms(reduced: dict | None) -> dict[str, float]:
-    """Device self milliseconds per traced step of each of ``READINGS``,
-    and ``data_idle``, idle milliseconds under ``repro.data`` per program
-    step; a reading with nothing to read is left out."""
-    out: dict[str, float] = {}
-    if not reduced or not reduced["steps"]:
-        return out
-    if reduced["scope_s"]:
-        for name, scopes in READINGS.items():
-            out[name] = (1e3 * sum(reduced["scope_s"][s] for s in scopes)
-                         / reduced["steps"])
-    if reduced["data_idle_s"] is not None and reduced["program_steps"]:
-        out["data_idle"] = 1e3 * reduced["data_idle_s"] / reduced[
-            "program_steps"]
-    return out
+def trace_record(path: str, hlo: str) -> dict | None:
+    """What a traced run's record keeps of the trace file at ``path``:
+    ``trace_reduce.reduce``'s fields and ``reduce``'s, from one reading;
+    None without a step span or a device operation."""
+    trace = load(path)
+    whole = trace_reduce.reduce(trace)
+    return None if whole is None else {**whole, **reduce(trace, hlo)}

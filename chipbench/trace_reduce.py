@@ -15,7 +15,9 @@ an asynchronous collective counts its start and its wait at its done,
 not what its transfer overlaps.  An operation's time is its self time:
 the ``XLA Ops`` line nests a loop's body inside the loop, and the body's
 operations are taken off the loop's.  An operation is named by its HLO
-instruction (``fusion.12``), which repeats in every trip of a loop.
+instruction (``fusion.12``), which repeats in every trip of a loop; its
+kind is that name without the instance number (``fusion``,
+``flash_attention``, ``all-gather``).
 
 Importing this module touches no accelerator.
 """
@@ -32,6 +34,8 @@ OPS_LINE = "XLA Ops"
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute|"
     r"allreduce|allgather|reducescatter|alltoall", re.IGNORECASE)
+
+_INSTANCE = re.compile(r"\.\d+$")
 
 Interval = tuple[float, float]          # (start, end) in seconds
 
@@ -93,6 +97,11 @@ def op_name(text: str) -> str:
     return head.lstrip("%")[:80]
 
 
+def kind_of(name: str) -> str:
+    """``fusion.12`` -> ``fusion``."""
+    return _INSTANCE.sub("", name)
+
+
 def self_times(ops: list[tuple[str, float, float]]
                ) -> list[tuple[str, float]]:
     """(name, self seconds) of nested operations on one line."""
@@ -124,8 +133,9 @@ def label(gap: Interval, spans: list[tuple[str, float, float]]) -> str:
 
 def reduce(trace: Trace, top: int = 10) -> dict | None:
     """Busy, idle and collective seconds averaged over devices, the top
-    operations, and the longest labelled gaps; None without a step span
-    or a device operation."""
+    operations, the longest labelled gaps, and ``op_ms_per_step``, the self
+    milliseconds per step of every kind of operation averaged over
+    devices; None without a step span or a device operation."""
     steps = [(s, e) for n, s, e in trace.host_spans if n == STEP_SPAN]
     devices = {d: v for d, v in trace.device_ops.items() if v}
     if not steps or not devices:
@@ -146,6 +156,9 @@ def reduce(trace: Trace, top: int = 10) -> dict | None:
         all_gaps.extend((label(g, trace.host_spans), g[1] - g[0])
                         for g in gaps(busy, lo, hi))
     n_dev = len(devices)
+    by_kind: collections.Counter = collections.Counter()
+    for n, t in per_op.items():
+        by_kind[kind_of(n)] += t
     all_gaps.sort(key=lambda g: -g[1])
     return {
         "window_s": hi - lo,
@@ -155,4 +168,6 @@ def reduce(trace: Trace, top: int = 10) -> dict | None:
         "devices": n_dev,
         "device_ops": [[n, t / n_dev] for n, t in per_op.most_common(top)],
         "idle_gaps": [[n, t] for n, t in all_gaps[:top]],
+        "op_ms_per_step": {k: 1e3 * t / (n_dev * len(steps))
+                           for k, t in by_kind.items()},
     }

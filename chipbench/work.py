@@ -14,6 +14,8 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
+from chipbench import reference
+
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
 
@@ -27,37 +29,30 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def _dense_forward_macs(c: dict, seq: int) -> float:
-    d, hd = c["hidden_size"], c["head_dim"]
-    hq, hkv = c["num_attention_heads"] * hd, c["num_key_value_heads"] * hd
-    proj = d * hq * 2 + d * hkv * 2 + 3 * d * c["intermediate_size"]
-    attn = 2 * hq * (seq + 1) / 2          # q.k and p.v over (t+1) keys
-    return c["num_hidden_layers"] * (proj + attn) + d * c["vocab_size"]
-
-
-def _rwkv6_forward_macs(c: dict, seq: int) -> float:
-    d, ff, lora = c["hidden_size"], c["intermediate_size"], 32
-    time_mix = 5 * d * d + 2 * (5 * lora) * d + 2 * lora * d
-    wkv = 2 * d * (d // c["num_attention_heads"])   # state update + read
-    channel_mix = 2 * d * ff + d * d
-    return (c["num_hidden_layers"] * (time_mix + wkv + channel_mix)
-            + d * c["vocab_size"])
-
-
-FORWARD_MACS = {"dense": _dense_forward_macs, "rwkv6": _rwkv6_forward_macs}
-
-
 def train_flops_per_token(c: dict, seq: int) -> float:
-    """Forward and backward FLOPs per token of a configuration file."""
-    return 3 * 2 * FORWARD_MACS[c["family"]](c, seq)
+    """Forward and backward FLOPs per token of a configuration file, from
+    its family's ``forward_macs``."""
+    return 3 * 2 * reference.family(c["family"]).forward_macs(c, seq)
+
+
+# the flash kernels (``kernels/flash_attention/kernel.py``): per call,
+# the dots of each (query, key) pair and the (b, s, h, d) tensors read or
+# written once.  forward: q.k^T and p.v; reads q, k, v, writes o.  dkv, the
+# dK/dV kernel: q.k^T, dO.v^T, p^T.dO and dS^T.q; reads q, k, v, dO,
+# writes dK, dV.  dq: q.k^T, dO.v^T and dS.k; reads q, k, v, dO, writes dQ.
+FLASH_CALLS = {"forward": (2, 4), "dkv": (4, 6), "dq": (3, 5)}
 
 
 def flash_attention(b: int, s: int, h: int, d: int, itemsize: int = 2,
-                    causal: bool = True) -> tuple[float, float]:
-    """(FLOPs, bytes) of one forward call: q.k and p.v over the keys each
-    query sees; q, k, v read and o written once."""
+                    causal: bool = True, call: str = "forward"
+                    ) -> tuple[float, float]:
+    """(FLOPs, bytes) of one call of the flash kernel ``call`` (a key of
+    ``FLASH_CALLS``), over the keys each query sees.  The row statistics
+    (logsumexp, delta) are left out of the bytes."""
+    dots, tensors = FLASH_CALLS[call]
     keys = (s + 1) / 2 if causal else s
-    return 4.0 * b * h * s * keys * d, 4.0 * b * s * h * d * itemsize
+    return (2.0 * dots * b * h * s * keys * d,
+            float(tensors) * b * s * h * d * itemsize)
 
 
 def rglru(b: int, s: int, r: int, itemsize: int = 4) -> tuple[float, float]:

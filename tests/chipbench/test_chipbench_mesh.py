@@ -13,7 +13,9 @@ of OLMo-1B's width in float32:
   change over three steps, leaf by leaf; and the step compiles once;
 * the harness's own run on that mesh, held to the four-chip cell's
   limits: sound, it comes out correct; with a step that returns its state
-  unchanged, or one that trains on half of the batch, it does not.
+  unchanged, or one that trains on half of the batch, it does not;
+* the step compiled afresh, as a traced run compiles it for the HLO text
+  that names its operations' scopes.
 """
 
 import json
@@ -46,7 +48,7 @@ CHANGE_RTOL = 1e-4   # three AdamW updates, m / sqrt(v) near +-lr for every
 CODE = """
 import dataclasses, json, sys, time
 import jax, jax.numpy as jnp, numpy as np
-from chipbench import check, data, reference, spec
+from chipbench import check, data, reference, scope_reduce, spec
 from chipbench.reference.common import key_from_seed, leaf_norms, make_weights
 from conftest import tiny_cell
 from repro.launch import train as launch
@@ -97,7 +99,14 @@ with jax.set_mesh(mesh):
     out["sharded_params"] = sum(
         not x.sharding.is_fully_replicated
         for x in jax.tree.leaves(state["params"]))
+    shapes = jax.tree.map(kind._abstract, state)
 del state
+# a traced run's second compile of the step, for its HLO text
+hlo = kind.step_hlo(cfg, tcfg, plan, mesh, shapes,
+                    {{k: jax.ShapeDtypeStruct(v.shape, jnp.int32)
+                      for k, v in rows[0].items()}})
+out["scopes"] = sorted(set().union(*map(
+    scope_reduce.scope_names, scope_reduce.hlo_op_names(hlo).values())))
 ref = reference.run(c, t["optimizer"], key, rows, jax.devices())
 out["losses"], out["ref_losses"] = losses, ref["losses"]
 out["grad_gap"] = check.worst_gap(grads, ref["grad_norms"])
@@ -162,6 +171,13 @@ def test_sharded_step_matches_reference(mesh_run):
         assert abs(got - want) <= LOSS_RTOL * abs(want), (got, want)
     assert mesh_run["grad_gap"] <= GRAD_RTOL
     assert mesh_run["change_gap"] <= CHANGE_RTOL
+
+
+def test_sharded_step_hlo_carries_the_scopes(mesh_run):
+    """The step a traced run compiles afresh for its HLO text, on the
+    mesh, names the program's five scopes."""
+    assert {"embed", "attention", "mlp", "head", "optimizer"} <= set(
+        mesh_run["scopes"])
 
 
 @pytest.mark.parametrize("fault", [None, "unchanged", "half_batch"])

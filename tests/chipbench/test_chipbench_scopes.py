@@ -1,6 +1,7 @@
 """Device time per named scope and device idle time under the program's
-data fetch, on HLO text and intervals made by hand, and on a small trace
-recorded on one v5e (at the end).
+data fetch, and the per-layer readers of the five scopes, on HLO text and
+intervals made by hand, and on a small trace recorded on one v5e (at the
+end).
 
 The module below holds, in the shape a TPU compile gives:
 
@@ -19,7 +20,18 @@ from pathlib import Path
 import pytest
 
 from chipbench import scope_reduce as sr
-from chipbench import trace_reduce
+from chipbench import spec, trace_reduce
+
+FIVE = {"embed", "attention", "mlp", "head", "optimizer"}
+READERS = {n: spec.metric_reader(f"{n}_ms_per_step.train")
+           for n in ("attention", "mlp", "vocab", "optimizer", "unscoped")}
+
+
+def readings(trace: dict | None) -> dict:
+    """The five scope readers on a record holding ``trace``; a reader
+    with nothing to read is left out."""
+    out = {n: read({"trace": trace}) for n, read in READERS.items()}
+    return {n: v for n, v in out.items() if v is not None}
 
 HLO = """\
 HloModule jit_train_step, is_scheduled=true
@@ -76,24 +88,24 @@ ENTRY %main.10 (p0: f32[64], p1: f32[64]) -> f32[64] {
 
 def test_fusion_is_charged_to_its_dot_else_its_root():
     names = sr.hlo_op_names(HLO)
-    got = {n: sr.scope_of(names[n]) for n in
+    got = {n: FIVE & sr.scope_names(names[n]) for n in
            ("fusion.1", "fusion.2", "fusion.3", "fusion.4", "copy.5",
             "while.6", "gather.7")}
-    assert got == {"fusion.1": "mlp", "fusion.2": "attention",
-                   "fusion.3": "head", "fusion.4": "optimizer",
-                   "copy.5": sr.UNSCOPED, "while.6": sr.UNSCOPED,
-                   "gather.7": "embed"}
+    assert got == {"fusion.1": {"mlp"}, "fusion.2": {"attention"},
+                   "fusion.3": {"head"}, "fusion.4": {"optimizer"},
+                   "copy.5": set(), "while.6": set(), "gather.7": {"embed"}}
 
 
-@pytest.mark.parametrize("op_name, scope", [
-    ("jit(f)/transpose(jvp(attention))/dot_general", "attention"),
-    ("jit(f)/optimizer/while/body/mlp/add", "mlp"),
-    ("jit(f)/attention_like/add", sr.UNSCOPED),
-    ("jit(f)/bsd,dh->bsh/dot_general", sr.UNSCOPED),
-    ("", sr.UNSCOPED),
+@pytest.mark.parametrize("op_name, scopes", [
+    ("jit(f)/transpose(jvp(attention))/dot_general", {"f", "attention"}),
+    ("jit(f)/optimizer/while/body/mlp/add",
+     {"f", "optimizer", "while", "body", "mlp"}),
+    ("jit(f)/attention_like/add", {"f", "attention_like"}),
+    ("jit(f)/transpose(jvp())/bsd,dh->bsh/dot_general", {"f", "bsd,dh->bsh"}),
+    ("", set()),
 ])
-def test_innermost_scope_wins(op_name, scope):
-    assert sr.scope_of(op_name) == scope
+def test_scope_names_of_an_op_name(op_name, scopes):
+    assert sr.scope_names(op_name) == scopes
 
 
 # two steps on one device (seconds): each a ``repro.step`` holding a
@@ -120,12 +132,17 @@ def reduced():
 
 
 def test_scopes_partition_the_self_time(reduced):
-    assert reduced["scope_s"] == {
-        "embed": 0.5, "attention": 0.5, "mlp": 1.0, "head": 1.0,
-        "optimizer": 1.5, sr.UNSCOPED: (1.5 - 0.5 - 0.5) + 0.25}
-    assert reduced["self_s"] == pytest.approx(5.25)
-    assert sum(reduced["scope_s"].values()) == pytest.approx(
-        reduced["self_s"])
+    # ms per step over the window's two steps; the loop's body (fusion.1,
+    # fusion.2) counts under while and body, and only copy.99, which the
+    # HLO text does not name, under no scope
+    assert reduced["scope_ms_per_step"] == pytest.approx({
+        "train_step": 2500.0, "while": 750.0, "body": 750.0,
+        "checkpoint": 500.0, "bsd,df->bsf": 500.0, "bsd,dv->bsv": 500.0,
+        "embed": 250.0, "attention": 250.0, "mlp": 500.0, "head": 500.0,
+        "optimizer": 750.0})
+    assert reduced["self_ms_per_step"] == pytest.approx(2625.0)
+    assert sum(readings(reduced).values()) == pytest.approx(
+        reduced["self_ms_per_step"])
 
 
 def test_idle_under_the_data_fetch(reduced):
@@ -133,14 +150,14 @@ def test_idle_under_the_data_fetch(reduced):
     # in the one program step that starts inside the window
     assert reduced["data_idle_s"] == pytest.approx(0.9)
     assert reduced["program_steps"] == 1
-    assert trace_reduce.reduce(TRACE)["busy_s"] == pytest.approx(
-        reduced["self_s"])
+    assert 1e3 * trace_reduce.reduce(TRACE)["busy_s"] / 2 == pytest.approx(
+        reduced["self_ms_per_step"])
 
 
 def test_readings_per_step(reduced):
-    assert sr.per_step_ms(reduced) == pytest.approx(
+    assert readings(reduced) == pytest.approx(
         {"attention": 250.0, "mlp": 500.0, "vocab": 750.0,
-         "optimizer": 750.0, "unscoped": 375.0, "data_idle": 900.0})
+         "optimizer": 750.0, "unscoped": 375.0})
 
 
 UNNAMED = (HLO.replace("mlp", "x").replace("attention", "x")
@@ -150,23 +167,30 @@ BARE = [(n, s, e) for n, s, e in SPANS if not n.startswith("repro.")]
 
 
 @pytest.mark.parametrize("spans, hlo, want", [
-    (BARE, UNNAMED, {}),
+    (BARE, "", set()),
     (BARE, HLO, {"attention", "mlp", "vocab", "optimizer", "unscoped"}),
     (SPANS, "", {"data_idle"}),
 ])
 def test_a_program_without_scopes_or_spans_reads_nothing(spans, hlo, want):
     r = sr.reduce(trace_reduce.Trace(device_ops={"/device:TPU:0": OPS},
                                      host_spans=spans), hlo)
-    assert (r["scope_s"] is None) == ("mlp" not in want)
+    assert (r["scope_ms_per_step"] == {}) == ("mlp" not in want)
     assert (r["data_idle_s"] is None) == ("data_idle" not in want)
-    assert set(sr.per_step_ms(r)) == set(want)
+    assert set(readings(r)) == want - {"data_idle"}
+
+
+def test_a_program_without_the_five_scopes_reads_zero_under_them():
+    r = sr.reduce(TRACE, UNNAMED)
+    assert readings(r) == pytest.approx(
+        {"attention": 0.0, "mlp": 0.0, "vocab": 0.0, "optimizer": 0.0,
+         "unscoped": r["self_ms_per_step"]})
 
 
 @pytest.mark.parametrize("reduced_", [
-    None, {"steps": 0, "scope_s": None, "data_idle_s": None,
-           "program_steps": 0}])
+    None, {"steps": 2, "self_ms_per_step": 1.0, "scope_ms_per_step": {},
+           "data_idle_s": None, "program_steps": 0}])
 def test_nothing_to_read(reduced_):
-    assert sr.per_step_ms(reduced_) == {}
+    assert readings(reduced_) == {}
 
 
 def test_no_device_op_or_no_step_span_reduces_to_none():
@@ -213,15 +237,49 @@ def test_recorded_scopes(recorded, scoped):
     assert scoped["steps"] == whole["steps"] == 3
     assert whole["window_s"] == pytest.approx(15089379e-9, abs=2e-9)
     assert whole["busy_s"] == pytest.approx(83082e-9, abs=2e-9)
-    assert scoped["self_s"] == pytest.approx(83082e-9, abs=2e-9)
-    want = {"attention": 22459, "mlp": 23706, sr.UNSCOPED: 36917,
-            "embed": 0, "head": 0, "optimizer": 0}
-    assert scoped["scope_s"] == pytest.approx(
-        {k: v * 1e-9 for k, v in want.items()}, abs=2e-9)
+    per_step = 1e-6 / 3                       # ns over three steps, in ms
+    assert scoped["self_ms_per_step"] == pytest.approx(83082 * per_step)
+    assert scoped["scope_ms_per_step"]["attention"] == pytest.approx(
+        22459 * per_step)
+    assert scoped["scope_ms_per_step"]["mlp"] == pytest.approx(
+        23706 * per_step)
+    assert not (FIVE - {"attention", "mlp"}) & set(
+        scoped["scope_ms_per_step"])
 
 
 def test_recorded_data_idle(scoped):
     assert scoped["data_idle_s"] == pytest.approx(11434908e-9, abs=2e-9)
     assert scoped["program_steps"] == 2
-    assert sr.per_step_ms(scoped)["data_idle"] == pytest.approx(
-        11434908e-6 / 2)
+
+
+@pytest.fixture(scope="module")
+def record():
+    """The trace part of a traced run's record, as the run makes it."""
+    return sr.trace_record(f"{SCOPED}.xplane.pb",
+                           Path(f"{SCOPED}.hlo.txt").read_text())
+
+
+def test_recorded_record_fields(record):
+    per_step = 1e-6 / 3
+    assert record["steps"] == 3 and record["devices"] == 1
+    assert record["busy_s"] == pytest.approx(83082e-9, abs=2e-9)
+    ops = record["op_ms_per_step"]
+    assert ops["fusion"] == pytest.approx((22459 + 23706) * per_step)
+    assert sum(ops.values()) == pytest.approx(record["self_ms_per_step"])
+    assert record["self_ms_per_step"] == pytest.approx(83082 * per_step)
+    assert record["data_idle_s"] == pytest.approx(11434908e-9, abs=2e-9)
+    assert len(record["device_ops"]) == 10
+
+
+def test_recorded_readers(record):
+    """The six readers on the recorded trace: the scopes add up to the
+    busy time, and with no ``flash_attention`` op there is no roofline."""
+    per_step = 1e-6 / 3
+    got = readings(record)
+    assert got == pytest.approx(
+        {"attention": 22459 * per_step, "mlp": 23706 * per_step,
+         "vocab": 0.0, "optimizer": 0.0, "unscoped": 36917 * per_step})
+    assert sum(got.values()) == pytest.approx(
+        1e3 * record["busy_s"] / record["steps"])
+    roofline = spec.metric_reader("flash_attention_roofline.train")
+    assert roofline({"trace": record, "config": {}, "traffic": {}}) is None
